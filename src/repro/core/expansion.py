@@ -74,12 +74,7 @@ def embedded_input_bytes(cfg: ModelConfig, shape: ShapeConfig,
 def profile_from_compiled(compiled, cfg: ModelConfig, shape: ShapeConfig,
                           n_devices: int, dp_size: int) -> MemoryProfile:
     ma = compiled.memory_analysis()
-    # peak_memory_in_bytes is a newer-JAX addition; fall back to the static
-    # sum (what peak_bytes reports anyway) on older versions.
-    reported = getattr(ma, "peak_memory_in_bytes", None)
-    if reported is None:
-        reported = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
-                    + ma.output_size_in_bytes)
+    reported = ma.peak_memory_in_bytes
     return MemoryProfile(
         arch=cfg.name,
         shape_name=shape.name,

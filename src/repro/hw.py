@@ -1,7 +1,8 @@
-"""TPU v5e hardware constants used by the roofline model and the WSMC planner.
+"""Hardware constants used by the roofline model and the WSMC planner.
 
-The container runs on CPU; TPU v5e is the *target* platform. All capacity
-planning and roofline terms are expressed against these constants.
+TPU v5e is the target platform. A run on a chip plans against that chip's
+entry in DEVICES (keyed by jax's `Device.device_kind`); planning on the CPU
+(the simulate backend, tests) targets the v5e entry explicitly.
 """
 from __future__ import annotations
 
@@ -31,6 +32,22 @@ TPU_V5E = HardwareSpec(
     ici_links_per_chip=4,
     vmem_bytes=128 * 1024 * 1024,
 )
+
+# One chip's peaks by `jax.Device.device_kind`. Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of chip-to-chip interconnect over 4 links).
+DEVICES = {"TPU v5 lite": TPU_V5E}
+
+
+def for_device_kind(kind: str) -> HardwareSpec:
+    """The table entry of a device kind; an unknown kind is an error, never
+    a silent fallback to another chip's numbers."""
+    try:
+        return DEVICES[kind]
+    except KeyError:
+        raise ValueError(f"no hardware entry for device kind {kind!r}; "
+                         f"known: {sorted(DEVICES)}") from None
+
 
 # The paper's Eq. 11 headroom factor: capacity = spark_mem * 4/3 + RM.
 # We keep 4/3 as the HBM fragmentation / runtime-scratch margin.

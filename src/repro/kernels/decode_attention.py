@@ -18,15 +18,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = lambda shape: pl.MemorySpace.ANY(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _scratch(shape):
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _kernel(pos_ref, cpos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -94,9 +92,9 @@ def decode_attention_fwd(q, k_cache, v_cache, cache_pos, positions, *,
         out_specs=pl.BlockSpec((1, K, G, hd), lambda bi, li: (bi, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, K, G, hd), q.dtype),
         scratch_shapes=[
-            _SCRATCH((K, G)),
-            _SCRATCH((K, G)),
-            _SCRATCH((K, G, hd)),
+            _scratch((K, G)),
+            _scratch((K, G)),
+            _scratch((K, G, hd)),
         ],
         interpret=interpret,
     )(positions.reshape(b, 1), cache_pos, q, k_cache, v_cache)
@@ -116,30 +114,53 @@ def decode_attention_fwd(q, k_cache, v_cache, cache_pos, positions, *,
 # table entries (-1) clamp to physical block 0 (the serving engine's
 # scratch block) and are masked out in-kernel.
 
-def _dequant_block(raw, scale_row, quant: str):
-    """In-kernel dequant of one pool block: raw [bs, K, hd] int8 or
-    [bs, K, hd//2] uint8 (packed nibbles, offset +8), scale_row [bs, K]
-    f32 per-token per-head absmax scales -> f32 [bs, K, hd]. This is the
-    fused path: the DMA moved quantized bytes; no fp pool ever exists."""
+def _lane_column(blk, j: int):
+    """Column j of a [rows, K] block as [rows, 1] — a masked lane reduce,
+    which the TPU lowers without a lane-to-sublane relayout."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    return jnp.sum(jnp.where(lane == j, blk, 0.0), axis=1, keepdims=True)
+
+
+def _dequant_rows(raw, scale_col, quant: str):
+    """In-kernel dequant of one kv head of one pool block: raw [bs, hd]
+    fp / int8, or [bs, hd//2] uint8 (packed nibbles, offset +8);
+    scale_col [bs, 1] f32 per-token absmax scales -> f32 [bs, hd]. This is
+    the fused path: the DMA moved quantized bytes; no fp pool ever exists.
+    (The int4 nibble interleave runs in interpret mode only.)"""
     if quant == "none":
         return raw.astype(jnp.float32)
     if quant == "int8":
-        return raw.astype(jnp.float32) * scale_row[..., None]
+        return raw.astype(jnp.float32) * scale_col
     lo = (raw & 0xF).astype(jnp.int32) - 8           # elements 0, 2, 4, ...
     hi = (raw >> 4).astype(jnp.int32) - 8            # elements 1, 3, 5, ...
-    bs, K, hd2 = raw.shape
-    full = jnp.stack([lo, hi], axis=-1).reshape(bs, K, hd2 * 2)
-    return full.astype(jnp.float32) * scale_row[..., None]
+    bs, hd2 = raw.shape
+    full = jnp.stack([lo, hi], axis=-1).reshape(bs, hd2 * 2)
+    return full.astype(jnp.float32) * scale_col
+
+
+def _block_mask(cpos, pos, window: Optional[int], chunk: Optional[int]):
+    """Which slots of a pool block (cpos [1, bs]) query position `pos`
+    attends: causal, written (pos >= 0), and inside the window / chunk."""
+    mask = (cpos <= pos) & (cpos >= 0)
+    if window is not None:
+        mask &= cpos > pos - window
+    if chunk is not None:
+        mask &= (cpos // chunk) == (pos // chunk)
+    return mask
 
 
 def _paged_kernel(tbl_ref, pos_ref, cpos_ref, q_ref, k_ref, v_ref, *refs,
                   scale: float, window: Optional[int], chunk: Optional[int],
-                  nl: int, quant: str = "none", mass: bool = False):
+                  nl: int, n_kv: int, quant: str = "none",
+                  mass: bool = False):
     # refs layout (flags append, never reorder):
     #   [ks_ref, vs_ref]  when quant != "none"   (per-row scale blocks)
     #   o_ref
     #   [bm_ref, bl_ref]  when mass              (per-block max / sumexp)
-    #   m_ref, l_ref, acc_ref                     (VMEM scratch)
+    #   m_ref, l_ref, acc_ref                     (VMEM scratch, per kv head)
+    # Every contraction is a 2-D matmul over ONE kv head: q [G, hd] against
+    # that head's rows [bs, hd] of the block, so the kernel needs no
+    # batched einsum and no in-kernel relayout of the [bs, K, hd] block.
     i = 0
     ks_ref = vs_ref = bm_ref = bl_ref = None
     if quant != "none":
@@ -153,6 +174,7 @@ def _paged_kernel(tbl_ref, pos_ref, cpos_ref, q_ref, k_ref, v_ref, *refs,
     m_ref, l_ref, acc_ref = refs[i:i + 3]
     bi = pl.program_id(0)
     li = pl.program_id(1)
+    f32 = jnp.float32
 
     @pl.when(li == 0)
     def _init():
@@ -171,42 +193,41 @@ def _paged_kernel(tbl_ref, pos_ref, cpos_ref, q_ref, k_ref, v_ref, *refs,
     # 0, but the compute is predicated off)
     @pl.when(tbl_ref[bi, li] >= 0)
     def _merge():
-        q = q_ref[0].astype(jnp.float32) * scale     # [K, G, hd]
-        k = _dequant_block(k_ref[0], None if ks_ref is None else ks_ref[0],
-                           quant)                    # [bs, K, hd]
-        v = _dequant_block(v_ref[0], None if vs_ref is None else vs_ref[0],
-                           quant)
-        pos = pos_ref[0, 0]                          # scalar
-        cpos = cpos_ref[0, :]                        # [bs]
-        s = jnp.einsum("kgh,lkh->kgl", q, k)         # [K, G, bs]
-        mask = (cpos <= pos) & (cpos >= 0)
-        if window is not None:
-            mask &= cpos > pos - window
-        if chunk is not None:
-            mask &= (cpos // chunk) == (pos // chunk)
-        s = jnp.where(mask[None, None, :], s, NEG_INF)
-        m_prev = m_ref[...]                          # [K, G]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.where(mask[None, None, :], jnp.exp(s - m_new[..., None]),
-                      0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum(
-            "kgl,lkh->kgh", p, v)
-        m_ref[...] = m_new
-        if mass:
-            # block-LOCAL softmax stats; combined across blocks outside
-            # the kernel (log-sum-exp merge, same algebra as (m, l))
-            bmax = s.max(axis=-1)                    # [K, G]
-            bm_ref[0, 0] = bmax
-            bl_ref[0, 0] = jnp.where(
-                mask[None, None, :], jnp.exp(s - bmax[..., None]),
-                0.0).sum(axis=-1)
+        mask = _block_mask(cpos_ref[0], pos_ref[bi], window, chunk)  # [1, bs]
+        ks = None if ks_ref is None else ks_ref[0].astype(f32)   # [bs, K]
+        vs = None if vs_ref is None else vs_ref[0].astype(f32)
+        for j in range(n_kv):
+            q = q_ref[0, j].astype(f32) * scale                  # [G, hd]
+            k = _dequant_rows(k_ref[0, :, j, :],
+                              None if ks is None else _lane_column(ks, j),
+                              quant)                             # [bs, hd]
+            v = _dequant_rows(v_ref[0, :, j, :],
+                              None if vs is None else _lane_column(vs, j),
+                              quant)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=f32)  # [G, bs]
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[j]                                    # [G, 1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[j] = l_ref[j] * corr + p.sum(axis=-1, keepdims=True)
+            acc_ref[j] = acc_ref[j] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=f32)
+            m_ref[j] = m_new
+            if mass:
+                # block-LOCAL softmax stats; combined across blocks outside
+                # the kernel (log-sum-exp merge, same algebra as (m, l))
+                bmax = s.max(axis=-1, keepdims=True)             # [G, 1]
+                bm_ref[0, 0, j] = bmax
+                bl_ref[0, 0, j] = jnp.where(
+                    mask, jnp.exp(s - bmax), 0.0).sum(axis=-1, keepdims=True)
 
     @pl.when(li == nl - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)[..., None]).astype(o_ref.dtype)
+        for j in range(n_kv):
+            o_ref[0, j] = (acc_ref[j] / jnp.maximum(l_ref[j], 1e-30)
+                           ).astype(o_ref.dtype)
 
 
 def paged_quant_of(k_pool) -> str:
@@ -247,60 +268,58 @@ def paged_decode_attention_fwd(q, k_pool, v_pool, pool_pos, block_tables,
     over heads) — the serving engine's block-retention signal. Per-block
     (max, sumexp) stats come out of the kernel and are merged outside with
     the standard log-sum-exp algebra."""
-    if pltpu is None:  # pragma: no cover
-        raise NotImplementedError("paged decode needs pallas TPU grid specs")
     b, K, G, hd = q.shape
     m_blocks = block_tables.shape[1]
-    bs = pool_pos.shape[1]
+    n_blocks, bs = pool_pos.shape
     quant = paged_quant_of(k_pool)
     if quant != "none" and (k_scales is None or v_scales is None):
         raise ValueError(f"{quant} pool needs k_scales/v_scales")
     hd_s = k_pool.shape[-1]                  # stored width (hd // 2 for int4)
     scale = 1.0 / np.sqrt(hd)
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
-                               chunk=chunk, nl=m_blocks, quant=quant,
+                               chunk=chunk, nl=m_blocks, n_kv=K, quant=quant,
                                mass=return_mass)
 
-    def physical(bi, li, tbl):
+    # index maps see the grid cell and both scalar-prefetch operands
+    # (block tables, decode positions); payload blocks chase the table
+    def physical(bi, li, tbl, pos):
         return jnp.maximum(tbl[bi, li], 0)
 
+    def per_lane(bi, li, tbl, pos):
+        return bi, 0, 0, 0
+
+    # positions ride as [n_blocks, 1, bs] so a block's row is a full-dim
+    # (1, bs) tile instead of a sub-tile (1, bs) slice of [n_blocks, bs]
     in_specs = [
-        pl.BlockSpec((1, 1), lambda bi, li, tbl: (bi, 0)),
-        pl.BlockSpec((1, bs), lambda bi, li, tbl: (physical(bi, li, tbl), 0)),
-        pl.BlockSpec((1, K, G, hd), lambda bi, li, tbl: (bi, 0, 0, 0)),
-        pl.BlockSpec((1, bs, K, hd_s),
-                     lambda bi, li, tbl: (physical(bi, li, tbl), 0, 0, 0)),
-        pl.BlockSpec((1, bs, K, hd_s),
-                     lambda bi, li, tbl: (physical(bi, li, tbl), 0, 0, 0)),
+        pl.BlockSpec((1, 1, bs), lambda *g: (physical(*g), 0, 0)),
+        pl.BlockSpec((1, K, G, hd), per_lane),
+        pl.BlockSpec((1, bs, K, hd_s), lambda *g: (physical(*g), 0, 0, 0)),
+        pl.BlockSpec((1, bs, K, hd_s), lambda *g: (physical(*g), 0, 0, 0)),
     ]
-    args = [block_tables, positions.reshape(b, 1), pool_pos, q,
-            k_pool, v_pool]
+    args = [block_tables.astype(jnp.int32), positions.astype(jnp.int32),
+            pool_pos.reshape(n_blocks, 1, bs), q, k_pool, v_pool]
     if quant != "none":
         # scale stripes chase the same block table as their payload
-        in_specs += [
-            pl.BlockSpec((1, bs, K),
-                         lambda bi, li, tbl: (physical(bi, li, tbl), 0, 0)),
-            pl.BlockSpec((1, bs, K),
-                         lambda bi, li, tbl: (physical(bi, li, tbl), 0, 0)),
-        ]
+        in_specs += [pl.BlockSpec((1, bs, K),
+                                  lambda *g: (physical(*g), 0, 0))] * 2
         args += [k_scales, v_scales]
-    out_specs = [pl.BlockSpec((1, K, G, hd),
-                              lambda bi, li, tbl: (bi, 0, 0, 0))]
+    out_specs = [pl.BlockSpec((1, K, G, hd), per_lane)]
     out_shape = [jax.ShapeDtypeStruct((b, K, G, hd), q.dtype)]
     if return_mass:
-        out_specs += [pl.BlockSpec((1, 1, K, G),
-                                   lambda bi, li, tbl: (bi, li, 0, 0))] * 2
-        out_shape += [jax.ShapeDtypeStruct((b, m_blocks, K, G),
+        out_specs += [pl.BlockSpec(
+            (1, 1, K, G, 1),
+            lambda bi, li, tbl, pos: (bi, li, 0, 0, 0))] * 2
+        out_shape += [jax.ShapeDtypeStruct((b, m_blocks, K, G, 1),
                                            jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, m_blocks),
         in_specs=in_specs,
         out_specs=out_specs if return_mass else out_specs[0],
         scratch_shapes=[
-            _SCRATCH((K, G)),
-            _SCRATCH((K, G)),
-            _SCRATCH((K, G, hd)),
+            _scratch((K, G, 1)),
+            _scratch((K, G, 1)),
+            _scratch((K, G, hd)),
         ],
     )
     out = pl.pallas_call(
@@ -311,7 +330,7 @@ def paged_decode_attention_fwd(q, k_pool, v_pool, pool_pos, block_tables,
     )(*args)
     if not return_mass:
         return out
-    o, bm, bl = out
+    o, bm, bl = out[0], out[1][..., 0], out[2][..., 0]
     # merge block-local (max, sumexp) into each block's global softmax
     # share: w_j = l_j * exp(m_j - M); mass_j = w_j / sum w
     M = bm.max(axis=1, keepdims=True)                # [b, 1, K, G]

@@ -13,18 +13,14 @@ make padding (-1) exact.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pltpu provides typed VMEM scratch; interpret mode works on CPU
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    _SCRATCH = lambda shape: pl.MemorySpace.ANY(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -55,36 +51,40 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale    # [qb, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [kb, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        qp = qpos_ref[0, :]                                   # [qb]
-        kp = kpos_ref[0, :]                                   # [kb]
+        q = q_ref[0, 0].astype(jnp.float32) * scale           # [qb, hd]
+        k = k_ref[0, 0].astype(jnp.float32)                   # [kb, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
+        qp = qpos_ref[0]                                      # [qb, 1]
+        kp = kpos_ref[0]                                      # [1, kb]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        mask = (kp[None, :] <= qp[:, None]) & (kp[None, :] >= 0)
+        mask = (kp <= qp) & (kp >= 0)
         if window is not None:
-            mask &= kp[None, :] > qp[:, None] - window
+            mask &= kp > qp - window
         if chunk is not None:
-            mask &= (kp[None, :] // chunk) == (qp[:, None] // chunk)
+            mask &= (kp // chunk) == (qp // chunk)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_ref[...]                                   # [qb, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        o_ref[0, :, 0, :] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
         # row log-sum-exp (saved for the backward kernels)
-        lse_ref[0, :, 0] = m_ref[...] + jnp.log(
-            jnp.maximum(l_ref[...], 1e-30))
+        lse_ref[0, 0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
+
+
+def _tile(s: int, block: int) -> int:
+    """Block length for a sequence of length s: `block`, or s rounded up
+    to the 128-lane tile when the whole sequence is shorter."""
+    return min(block, -(-s // 128) * 128)
 
 
 def flash_attention_fwd(q, k, v, qpos, kpos, *,
@@ -93,14 +93,23 @@ def flash_attention_fwd(q, k, v, qpos, kpos, *,
                         q_block: int = 512, kv_block: int = 512,
                         interpret: bool = False, return_lse: bool = False):
     """q [b,s,H,hd]; k/v [b,s,K,hd]; qpos/kpos [b,s] -> out [b,s,H,hd]
-    (+ lse [b,s,H] when return_lse — consumed by flash_attention_bwd)."""
+    (+ lse [b,s,H] when return_lse — consumed by flash_attention_bwd).
+
+    The kernel runs head-major ([b, H, s, hd] tiles of (block, hd)) with
+    query positions as a column and key positions as a row, so every
+    block's trailing two dims are TPU tiles. A sequence that the blocks do
+    not divide is padded with position -1 (masked) and cropped after."""
     b, s, H, hd = q.shape
     K = k.shape[2]
     G = H // K
-    q_block = min(q_block, s)
-    kv_block = min(kv_block, s)
-    assert s % q_block == 0 and s % kv_block == 0, (s, q_block, kv_block)
-    nq, nk = s // q_block, s // kv_block
+    q_block, kv_block = _tile(s, q_block), _tile(s, kv_block)
+    s_pad = -(-s // math.lcm(q_block, kv_block)) * math.lcm(q_block, kv_block)
+    if s_pad != s:
+        pad = ((0, 0), (0, s_pad - s))
+        q, k, v = (jnp.pad(t, pad + ((0, 0), (0, 0))) for t in (q, k, v))
+        qpos = jnp.pad(qpos, pad, constant_values=-1)
+        kpos = jnp.pad(kpos, pad, constant_values=-1)
+    nq, nk = s_pad // q_block, s_pad // kv_block
     grid = (b, H, nq, nk)
     scale = 1.0 / np.sqrt(hd)
 
@@ -108,34 +117,37 @@ def flash_attention_fwd(q, k, v, qpos, kpos, *,
         _kernel, scale=scale, window=window, chunk=chunk,
         q_block=q_block, kv_block=kv_block, nk=nk)
 
+    heads_major = lambda t: jnp.swapaxes(t, 1, 2)             # [b,h,s,hd]
+    q_tile = pl.BlockSpec((1, 1, q_block, hd),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    kv_tile = pl.BlockSpec((1, 1, kv_block, hd),
+                           lambda bi, hi, qi, ki: (bi, hi // G, ki, 0))
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q_block), lambda bi, hi, qi, ki: (bi, qi)),
-            pl.BlockSpec((1, kv_block), lambda bi, hi, qi, ki: (bi, ki)),
-            pl.BlockSpec((1, q_block, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // G, 0)),
-            pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // G, 0)),
+            pl.BlockSpec((1, q_block, 1), lambda bi, hi, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, kv_block), lambda bi, hi, qi, ki: (bi, 0, ki)),
+            q_tile, kv_tile, kv_tile,
         ],
         out_specs=[
-            pl.BlockSpec((1, q_block, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, q_block, 1),
-                         lambda bi, hi, qi, ki: (bi, qi, hi)),
+            q_tile,
+            pl.BlockSpec((1, 1, q_block, 1),
+                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, H, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, s, H), jnp.float32),
+            jax.ShapeDtypeStruct((b, H, s_pad, hd), q.dtype),
+            jax.ShapeDtypeStruct((b, H, s_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _SCRATCH((q_block,)),       # m
-            _SCRATCH((q_block,)),       # l
-            _SCRATCH((q_block, hd)),    # acc
+            pltpu.VMEM((q_block, 1), jnp.float32),      # m
+            pltpu.VMEM((q_block, 1), jnp.float32),      # l
+            pltpu.VMEM((q_block, hd), jnp.float32),     # acc
         ],
         interpret=interpret,
-    )(qpos, kpos, q, k, v)
-    return (out, lse) if return_lse else out
+    )(qpos.astype(jnp.int32)[..., None], kpos.astype(jnp.int32)[:, None, :],
+      heads_major(q), heads_major(k), heads_major(v))
+    out = heads_major(out)[:, :s]
+    if not return_lse:
+        return out
+    return out, jnp.swapaxes(lse[..., 0], 1, 2)[:, :s]

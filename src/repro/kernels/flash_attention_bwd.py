@@ -24,14 +24,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    _SCRATCH = lambda shape: pl.MemorySpace.ANY(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _scratch(shape):
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _mask_block(qp, kp, window, chunk):
@@ -179,7 +178,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, qpos, kpos, *,
         out_specs=pl.BlockSpec((1, q_block, 1, hd),
                                lambda bi, hi, i, j: (bi, i, hi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, H, hd), q.dtype),
-        scratch_shapes=[_SCRATCH((q_block, hd))],
+        scratch_shapes=[_scratch((q_block, hd))],
         interpret=interpret,
     )(qpos, kpos, q, k, v, do, lse, dvec)
 
@@ -214,7 +213,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, qpos, kpos, *,
         ],
         out_shape=[jax.ShapeDtypeStruct((b, s, H, hd), k.dtype),
                    jax.ShapeDtypeStruct((b, s, H, hd), v.dtype)],
-        scratch_shapes=[_SCRATCH((kv_block, hd)), _SCRATCH((kv_block, hd))],
+        scratch_shapes=[_scratch((kv_block, hd)), _scratch((kv_block, hd))],
         interpret=interpret,
     )(qpos, kpos, q, k, v, do, lse, dvec)
     return dq, dk, dv

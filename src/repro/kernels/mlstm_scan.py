@@ -18,14 +18,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    _SCRATCH = lambda shape: pl.MemorySpace.ANY(shape, jnp.float32)
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _scratch(shape):
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _kernel(q_ref, k_ref, v_ref, i_ref, f_ref, c0_ref, n0_ref, m0_ref,
@@ -148,9 +147,9 @@ def mlstm_scan_fwd(q, k, v, i_gate, f_gate, *, chunk: int = 128,
             jax.ShapeDtypeStruct((bh, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _SCRATCH((dk, dv)),
-            _SCRATCH((dk, 1)),
-            _SCRATCH((1, 1)),
+            _scratch((dk, dv)),
+            _scratch((dk, 1)),
+            _scratch((1, 1)),
         ],
         interpret=interpret,
     )(q, k, v, i_gate, f_gate, C0, n0, m0)

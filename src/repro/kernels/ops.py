@@ -1,27 +1,29 @@
 """jit'd wrappers around the Pallas kernels with backend dispatch.
 
 Backends:
-  pallas    — compiled pallas_call (TPU target)
+  pallas    — compiled pallas_call on a TPU; off-TPU, where no Pallas
+              compiler exists, the same kernel body in the interpreter
   interpret — pallas_call(interpret=True): kernel body evaluated on CPU;
-              used by the allclose test sweeps
+              used by the allclose test sweeps. Refused on a TPU.
   blocked   — memory-equivalent pure-jnp tiling (lax.scan) — what the CPU
               dry-run lowers, keeping the compile-visible memory footprint
               faithful to the kernel's
   ref       — kernels.ref oracles (small shapes only)
 
-Default: pallas on TPU, blocked elsewhere. Override per call or with env
-REPRO_KERNEL_BACKEND.
+Default: pallas on TPU, blocked elsewhere (`select_backend`). Override per
+call or with env REPRO_KERNEL_BACKEND.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import functools
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import functools
 
 from repro.kernels import ref as kref
 from repro.kernels.flash_attention import flash_attention_fwd
@@ -32,13 +34,60 @@ from repro.kernels.mlstm_scan import mlstm_scan_fwd
 from repro.kernels.prefill_attention import paged_prefill_attention_fwd
 
 NEG_INF = -1e30
+BACKENDS = ("pallas", "interpret", "blocked", "ref")
+
+_RECORDERS: list = []
+
+
+@contextlib.contextmanager
+def record_traces():
+    """Count, while the block runs, which implementation each attention
+    path was traced with: {(path, impl): times}, impl being "compiled" /
+    "interpret" (Pallas) or "jnp". A step traces once per compile, so this
+    names what the compiled steps run."""
+    rec: collections.Counter = collections.Counter()
+    _RECORDERS.append(rec)
+    try:
+        yield rec
+    finally:
+        _RECORDERS.remove(rec)
+
+
+def note_trace(path: str, impl: str) -> None:
+    for rec in _RECORDERS:
+        rec[(path, impl)] += 1
+
+
+def select_backend(platform: str, requested: Optional[str] = None) -> str:
+    """The kernel backend for a process on `platform` (jax.default_backend()
+    naming): `requested` (the REPRO_KERNEL_BACKEND override) if given, else
+    compiled Pallas on a TPU and the blocked jnp tiling elsewhere. Interpret
+    mode is never chosen on a TPU: asking for it there is an error."""
+    backend = requested or ("pallas" if platform == "tpu" else "blocked")
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown kernel backend {backend!r}; "
+                         f"expected one of {BACKENDS}")
+    if platform == "tpu" and backend == "interpret":
+        raise ValueError("the Pallas interpreter is not run on a TPU; "
+                         "use the compiled 'pallas' backend there")
+    return backend
 
 
 def default_backend() -> str:
-    env = os.environ.get("REPRO_KERNEL_BACKEND")
-    if env:
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "blocked"
+    return select_backend(jax.default_backend(),
+                          os.environ.get("REPRO_KERNEL_BACKEND") or None)
+
+
+def _interpret(backend: str, path: str) -> bool:
+    """pallas_call's `interpret` flag for a Pallas backend: compiled on a
+    TPU, interpreted elsewhere (there is no Pallas compiler for the CPU)."""
+    platform = jax.default_backend()
+    backend = select_backend(platform, backend)
+    if backend not in ("pallas", "interpret"):
+        raise ValueError(f"backend {backend!r} runs no Pallas kernel")
+    interpret = platform != "tpu"
+    note_trace(path, "interpret" if interpret else "compiled")
+    return interpret
 
 
 # ---------------------------------------------------------------------------
@@ -58,8 +107,8 @@ def flash_attention(q, k, v, qpos, kpos, *, window: Optional[int] = None,
         qf = q.reshape(b, s, K * G, hd)
         out = flash_attention_fwd(
             qf, k, v, qpos, kpos, window=window, chunk=chunk,
-            q_block=min(q_block, s), kv_block=min(kv_block, s),
-            interpret=(backend == "interpret"))
+            q_block=q_block, kv_block=kv_block,
+            interpret=_interpret(backend, "prompt_prefill"))
         return out.reshape(b, s, K, G, hd)
     # blocked jnp fallback lives in models.attention (shared tiling logic)
     from repro.models import attention as mattn
@@ -132,7 +181,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, positions, *,
         return decode_attention_fwd(
             q, k_cache, v_cache, cache_pos, positions,
             window=window, chunk=chunk, kv_block=kv_block,
-            interpret=(backend == "interpret"))
+            interpret=_interpret(backend, "ring_decode"))
     return kref.decode_attention_ref(q, k_cache, v_cache, cache_pos,
                                      positions, window=window, chunk=chunk)
 
@@ -140,7 +189,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, positions, *,
 def paged_decode_attention(q, k_pool, v_pool, pool_pos, block_tables,
                            positions, *, window: Optional[int] = None,
                            chunk: Optional[int] = None,
-                           backend: Optional[str] = None,
+                           backend: str = "pallas",
                            k_scales=None, v_scales=None,
                            return_mass: bool = False):
     """Decode through a paged KV pool: q [b,K,G,hd]; pools
@@ -149,23 +198,21 @@ def paged_decode_attention(q, k_pool, v_pool, pool_pos, block_tables,
     [n_blocks,block]; block_tables [b,max_blocks] (-1 = unassigned) ->
     [b,K,G,hd], or (out, mass [b,max_blocks]) with `return_mass`.
     Quantized pools are DMA'd and dequantized inside the kernel — no fp
-    pool copy. Compiled Pallas on TPU; interpret-mode kernel everywhere
-    else (the CPU test tiers drive the same block-table indirection the
-    TPU kernel runs)."""
-    backend = backend or default_backend()
-    if backend not in ("pallas", "interpret"):
-        backend = "interpret"       # no jnp twin: the kernel IS the gather
+    pool copy. There is no jnp twin here (the kernel IS the gather; the
+    jnp path lives in models.attention): `backend` is "pallas" —
+    compiled on a TPU, interpreted elsewhere — or "interpret" off-TPU."""
     return paged_decode_attention_fwd(
         q, k_pool, v_pool, pool_pos, block_tables, positions,
         window=window, chunk=chunk, k_scales=k_scales, v_scales=v_scales,
-        return_mass=return_mass, interpret=(backend == "interpret"))
+        return_mass=return_mass,
+        interpret=_interpret(backend, "decode"))
 
 
 def paged_prefill_attention(q, k_new, v_new, k_pool, v_pool, pool_pos,
                             block_tables, positions, *,
                             window: Optional[int] = None,
                             chunk: Optional[int] = None,
-                            backend: Optional[str] = None,
+                            backend: str = "pallas",
                             k_scales=None, v_scales=None):
     """Fused chunked prefill through a paged KV pool: write the chunk's
     K/V into the pool via the block tables (quantize-on-write in-kernel
@@ -176,15 +223,12 @@ def paged_prefill_attention(q, k_new, v_new, k_pool, v_pool, pool_pos,
     (bf16, int8, or uint8-packed int4 with f32 `k_scales`/`v_scales`);
     pool_pos [n_blocks,block]; block_tables [b,max_blocks]; positions
     [b,C] (-1 = padding). Returns (o, pool_pos', k_pool', v_pool'[, ks',
-    vs']). Compiled Pallas on TPU; interpret-mode elsewhere — like paged
-    decode there is no jnp twin: the kernel IS the scatter + gather."""
-    backend = backend or default_backend()
-    if backend not in ("pallas", "interpret"):
-        backend = "interpret"
+    vs']). Like paged decode there is no jnp twin: the kernel IS the
+    scatter + gather, and `backend` is "pallas" or "interpret"."""
     return paged_prefill_attention_fwd(
         q, k_new, v_new, k_pool, v_pool, pool_pos, block_tables, positions,
         window=window, chunk_mask=chunk, k_scales=k_scales,
-        v_scales=v_scales, interpret=(backend == "interpret"))
+        v_scales=v_scales, interpret=_interpret(backend, "chunk_prefill"))
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +321,8 @@ def mlstm_scan(q, k, v, i_gate, f_gate, *, chunk: int = 128,
                     initial[2].reshape(b * h, 1)))
     if backend in ("pallas", "interpret"):
         out, (C, n, m) = mlstm_scan_fwd(qf, kf, vf, igf, fgf, chunk=chunk,
-                                        interpret=(backend == "interpret"),
+                                        interpret=_interpret(backend,
+                                                             "mlstm_scan"),
                                         initial=init_f)
     else:
         init_j = None if init_f is None else (init_f[0], init_f[1],

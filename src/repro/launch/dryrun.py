@@ -178,7 +178,7 @@ def run_cell(arch: str, shape: ShapeConfig,
         if mesh_name == "single" and measurer.last_compiled is not None:
             # raw HLO flops only exist under the compile backend (and only
             # when the profile wasn't served from the cache)
-            ca = RA.cost_dict(measurer.last_compiled)
+            ca = measurer.last_compiled.cost_analysis()
             print(f"[{arch} × {shape.name} × {mesh_name}] cost_analysis "
                   f"(scan counts body once): flops={ca.get('flops', 0):.3e}",
                   flush=True)

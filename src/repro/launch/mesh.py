@@ -12,15 +12,11 @@ CANONICAL_AXES = ("pod", "pipe", "data", "model")
 
 
 def _make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across JAX versions: `axis_types` (and the
-    jax.sharding.AxisType enum backing it) only exists on newer releases;
-    older ones default every axis to Auto anyway, which is what we want."""
-    kw = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes), **kw)
-    return jax.make_mesh(shape, axes, **kw)
+    """A mesh whose axes are all Auto: shardings propagate through GSPMD and
+    the model's `shard` hooks add constraints."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

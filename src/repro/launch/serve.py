@@ -15,19 +15,27 @@ Example (CPU, reduced config):
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-12b --reduced \
       --requests 8 --prompt-lens 4,8 --gen-lens 2,4,8 [--mesh auto] \
       [--backend simulate|compile] [--policy continuous|static|both]
+
+On a TPU the default HBM budget is the chip's own (hw.DEVICES, keyed by
+device kind) and attention runs the compiled Pallas kernels; `--depth N`
+cuts a published-width model to N unit repeats so it fits one chip.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 import jax
 
+from repro import hw as HW
 from repro.configs import get_config
-from repro.configs.base import DECODE, ShapeConfig
+from repro.configs.base import DECODE, ShapeConfig, depth_variant
 from repro.core import measure as MM
 from repro.core.predictor import MemoryPlan
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import init_params
+from repro.parallel import sharding as SH
 from repro.parallel.axes import axis_rules
 from repro.search import execplan as XP
 from repro.search import space as SP
@@ -43,10 +51,17 @@ def _int_list(s: str):
     return tuple(int(v) for v in s.split(",") if v)
 
 
-def main(argv=None):
+def main(argv=None, out: Optional[dict] = None):
+    """Serve the trace; returns the exit code. A caller that passes an
+    `out` dict gets the plan's per-device budget and Eq. 11 requirement
+    ceiling and the engine reports filled into it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--depth", type=int, default=0,
+                    help="override depth to N unit repeats at the "
+                         "config's published widths (fits a model to "
+                         "fewer chips; 0 = published depth)")
     # trace knobs (deterministic: same seed + knobs => same trace)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-lens", type=_int_list, default=(4, 8))
@@ -68,7 +83,8 @@ def main(argv=None):
                          "simulate = zero throwaway compiles at startup")
     ap.add_argument("--hbm-budget-gb", type=float, default=0.0,
                     help="per-device HBM budget for admission; 0 = the "
-                         "target hardware's full HBM")
+                         "HBM of the chip the run is on (v5e when planning "
+                         "on the CPU)")
     ap.add_argument("--kv", default="ring", choices=["ring", "paged"],
                     help="KV pool layout: 'ring' = worst-case whole-"
                          "sequence slots (baseline); 'paged' = block pool "
@@ -212,10 +228,15 @@ def main(argv=None):
                  "BlockAllocator ledger)")
     if args.deadline < 0:
         ap.error("--deadline must be >= 0")
+    if args.depth < 0:
+        ap.error("--depth must be >= 0")
 
+    setup_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.depth:
+        cfg = depth_variant(cfg, args.depth)
     trace = synthetic_trace(args.requests, vocab_size=cfg.vocab_size,
                             seed=args.seed, prompt_lens=args.prompt_lens,
                             gen_lens=args.gen_lens,
@@ -224,6 +245,9 @@ def main(argv=None):
                             slo_classes=args.slo)
     context = args.context or trace_context(trace)
     devices = jax.devices()
+    # the chip's own table entry; CPU planning targets v5e explicitly
+    hw = (HW.for_device_kind(devices[0].device_kind)
+          if devices[0].platform == "tpu" else HW.TPU_V5E)
     shape = ShapeConfig("serve_trace", DECODE, context,
                         max(args.max_slots, 1))
     budget = (args.hbm_budget_gb * 2**30) if args.hbm_budget_gb else None
@@ -270,7 +294,7 @@ def main(argv=None):
                 measurer = MM.CompileMeasurer(
                     build_mesh({"data": len(devices)}, devices))
             cls, splan = XP.plan_serving(cfg, shape, n_devices=len(devices),
-                                         hbm_budget=budget,
+                                         hbm_budget=budget, hw=hw,
                                          measurer=measurer, **paged_kw)
         else:
             host = XP.host_execution(cfg, shape, MemoryPlan(),
@@ -289,7 +313,7 @@ def main(argv=None):
                 kv_retains=((args.kv_retain,) if args.kv == "paged"
                             else (0,)))
             cls, splan = XP.plan_serving(cfg, shape, n_devices=len(devices),
-                                         hbm_budget=budget,
+                                         hbm_budget=budget, hw=hw,
                                          measurer=measurer, space=pinned,
                                          **paged_kw)
     finally:
@@ -322,7 +346,20 @@ def main(argv=None):
         print("chaos:", plan.describe())
 
     # -- serve --------------------------------------------------------------
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
+    # parameters are drawn straight into the plan's shardings
+    key = jax.random.PRNGKey(args.seed)
+    abstract = jax.eval_shape(lambda k: init_params(k, cfg), key)
+    params = init_params(key, cfg, SH.to_named(
+        mesh, SH.param_specs(cfg, abstract, strategy, mesh)))
+    # Eq. 11: capacity = requirement * 4/3 + reserve <= budget, so the
+    # plan promises each device at most this requirement
+    promised = (splan.hbm_budget - hw.reserved_bytes) / HW.CAPACITY_HEADROOM
+    print(f"plan: per-device budget={splan.hbm_budget:.0f} B, Eq.11 "
+          f"requirement <= {promised:.0f} B; lanes={n_slots} "
+          f"pool_blocks={n_blocks} kv_block={splan.kv_block}")
+    if out is not None:
+        out.update(budget_bytes=splan.hbm_budget, promised_bytes=promised,
+                   reports=[])
     policies = (["continuous", "static"] if args.policy == "both"
                 else [args.policy])
     reports = []
@@ -380,6 +417,8 @@ def main(argv=None):
             tp = report.ttft_percentiles()
             print(report.describe() + f" wall={dt:.2f}s "
                   f"compiles={executor.compile_counts()}")
+            if out is not None:
+                out["reports"].append(report)
             if lp and tp:  # both empty when nothing completed
                 print(f"  latency p50/p95/p99={lp['p50']:.0f}/"
                       f"{lp['p95']:.0f}/{lp['p99']:.0f} ticks "

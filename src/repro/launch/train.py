@@ -35,6 +35,7 @@ from repro.core import measure as MM
 from repro.core import planner as PL
 from repro.core import profiler as PF
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import build_mesh
 from repro.models import init_params
 from repro.optim import optimizers as opt
@@ -146,6 +147,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    setup_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced_100m:
         cfg = reduced_100m(cfg)

@@ -6,7 +6,10 @@ Backends:
             * global causal: q-block × kv-block online-softmax scans
             * sliding window: exact per-q-block KV slices (linear memory)
             * chunked-local: chunks folded into batch, causal within chunk
-  pallas  — kernels.ops.flash_attention (TPU target; interpret-mode on CPU).
+  pallas  — the Pallas kernels (kernels.ops): flash attention for whole
+            prompts, the paged decode / chunked-prefill kernels for the
+            block pool. Compiled on a TPU, interpret-mode on CPU. The
+            serving steps take it on a TPU (models.model.serving_settings).
 
 Decode uses a unified ring-buffer KV cache: slot = position % cache_len with
 absolute positions stored alongside for mask reconstruction — one layout
@@ -21,9 +24,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import BlockSpec, ModelConfig
+from repro.kernels import ops as kops
 from repro.models import layers
+from repro.parallel import axes as pax
 from repro.parallel.axes import gather_fsdp, shard
 
 NEG_INF = -1e30
@@ -80,6 +86,48 @@ def _mask(qpos, kpos, blk: BlockSpec):
     if blk.chunk is not None:
         m &= (k // blk.chunk) == (q // blk.chunk)
     return m
+
+
+# ---------------------------------------------------------------------------
+# Pallas calls under a mesh
+# ---------------------------------------------------------------------------
+
+def _over_kv_heads(fn, args, head_axes, out_axes):
+    """Call a Pallas kernel wrapper once per device on its KV-head shard.
+
+    A pallas_call is a custom call the SPMD partitioner cannot split, so
+    when the ambient mesh shards "kv_heads" the call runs under
+    jax.shard_map: each array in `args` is split on its `head_axes` entry
+    (None = replicated) and each output on its `out_axes` entry — None for
+    an output every shard computes identically, "mean" for one averaged
+    over heads (combined with a pmean). Without such a mesh this is just
+    fn(*args)."""
+    mesh = pax.current_mesh()
+    axis = None
+    if mesh is not None and not pax.annotations_suspended():
+        n_kv = args[0].shape[head_axes[0]]
+        axis = pax.logical_to_spec(("kv_heads",), mesh=mesh,
+                                   shape=(n_kv,))[0]
+    if axis is None:
+        return fn(*args)
+    single = not isinstance(out_axes, tuple)
+    outs = (out_axes,) if single else out_axes
+
+    def split(ax):
+        return P() if ax in (None, "mean") else P(*([None] * ax + [axis]))
+
+    def body(*local):
+        with pax.suspend_annotations():
+            res = fn(*local)
+        res = (res,) if single else tuple(res)
+        res = tuple(jax.lax.pmean(r, axis) if ax == "mean" else r
+                    for r, ax in zip(res, outs))
+        return res[0] if single else res
+
+    out_specs = split(out_axes) if single else tuple(split(a) for a in outs)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=tuple(split(a) for a in head_axes),
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -212,9 +260,11 @@ def _chunked(q, k, v, qpos, kpos, blk: BlockSpec, set_: AttnSettings):
 
 def _seq_attention(q, k, v, qpos, kpos, blk, set_: AttnSettings):
     if set_.backend == "pallas":
-        from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, qpos, kpos,
-                                    window=blk.window, chunk=blk.chunk)
+        return _over_kv_heads(
+            functools.partial(kops.flash_attention, window=blk.window,
+                              chunk=blk.chunk, backend="pallas"),
+            (q, k, v, qpos, kpos), (2, 2, 2, None, None), 2)
+    kops.note_trace("prompt_prefill", "jnp")
     if set_.backend == "naive":
         return _naive(q, k, v, qpos, kpos, blk)
     if blk.window is not None:
@@ -461,18 +511,27 @@ def _chunk_append(q, k, v, cache, blk: BlockSpec, positions, block_tables,
         assert block_tables is not None, \
             "paged cache needs block_tables for chunked prefill"
         if settings.backend == "pallas":
-            from repro.kernels import ops as kops
             quant = paged_quant_kind(cache)
-            out = kops.paged_prefill_attention(
-                q, k, v, cache["kb"], cache["vb"], cache["pos"],
-                block_tables, positions, window=blk.window, chunk=blk.chunk,
-                k_scales=(cache["ks"] if quant != "none" else None),
-                v_scales=(cache["vs"] if quant != "none" else None))
+            scales = ((cache["ks"], cache["vs"]) if quant != "none" else ())
+
+            def prefill(q, k, v, kb, vb, pos, tables, qpos, *scales):
+                ks, vs = scales or (None, None)
+                return kops.paged_prefill_attention(
+                    q, k, v, kb, vb, pos, tables, qpos, window=blk.window,
+                    chunk=blk.chunk, backend="pallas", k_scales=ks,
+                    v_scales=vs)
+
+            out = _over_kv_heads(
+                prefill, (q, k, v, cache["kb"], cache["vb"], cache["pos"],
+                          block_tables, positions) + scales,
+                (2, 2, 2, 2, 2, None, None, None) + (2,) * len(scales),
+                (2, None, 2, 2) + (2,) * len(scales))
             o, ppos, kb, vb = out[:4]
             new_cache = {"kb": kb, "vb": vb, "pos": ppos}
             if quant != "none":
                 new_cache["ks"], new_cache["vs"] = out[4], out[5]
             return o, new_cache
+        kops.note_trace("chunk_prefill", "jnp")
         new_cache = _paged_write_chunk(cache, block_tables, k, v, positions)
         virt = _paged_gather(new_cache, block_tables)
         o = _sdpa(q, virt["k"], virt["v"],
@@ -512,18 +571,27 @@ def _paged_decode(q, cache, blk: BlockSpec, pos1, k1, v1, block_tables,
     b, m_blocks = block_tables.shape
     bsz = cache["pos"].shape[1]
     if settings.backend == "pallas":
-        from repro.kernels import ops as kops
         quant = paged_quant_kind(new_cache)
-        out = kops.paged_decode_attention(
-            q[:, 0], new_cache["kb"], new_cache["vb"], new_cache["pos"],
-            block_tables, pos1, window=blk.window, chunk=blk.chunk,
-            k_scales=(new_cache["ks"] if quant != "none" else None),
-            v_scales=(new_cache["vs"] if quant != "none" else None),
-            return_mass=settings.track_mass)
+        scales = ((new_cache["ks"], new_cache["vs"]) if quant != "none"
+                  else ())
+
+        def decode(q1, kb, vb, pos, tables, pos1, *scales):
+            ks, vs = scales or (None, None)
+            return kops.paged_decode_attention(
+                q1, kb, vb, pos, tables, pos1, window=blk.window,
+                chunk=blk.chunk, backend="pallas", k_scales=ks, v_scales=vs,
+                return_mass=settings.track_mass)
+
+        out = _over_kv_heads(
+            decode, (q[:, 0], new_cache["kb"], new_cache["vb"],
+                     new_cache["pos"], block_tables, pos1) + scales,
+            (1, 2, 2, None, None, None) + (2,) * len(scales),
+            (1, "mean") if settings.track_mass else 1)
         if settings.track_mass:
             o, mass = out
             return o[:, None], new_cache, mass
         return out[:, None], new_cache, None
+    kops.note_trace("decode", "jnp")
     virt = _paged_gather(new_cache, block_tables)
     if settings.track_mass:
         o, p = _decode_attend(q, virt, blk, pos1, return_probs=True)

@@ -38,6 +38,16 @@ class ModelSettings:
                                             # dispatch FLOPs/bytes ∝ group
 
 
+def serving_settings() -> ModelSettings:
+    """The serving steps' settings when the caller passes none: attention
+    takes this process's kernel backend (kernels.ops.default_backend) —
+    the compiled Pallas kernels on a TPU for whole-prompt prefill, paged
+    decode and paged chunked prefill; the blocked jnp paths elsewhere."""
+    from repro.kernels import ops as kops
+    return ModelSettings(
+        attn=attention.AttnSettings(backend=kops.default_backend()))
+
+
 # ---------------------------------------------------------------------------
 # Dense MLP
 # ---------------------------------------------------------------------------
@@ -165,7 +175,20 @@ def block_apply(params, cfg: ModelConfig, blk: BlockSpec, x, positions,
 # Parameter / cache trees
 # ---------------------------------------------------------------------------
 
-def init_params(key, cfg: ModelConfig):
+def init_params(key, cfg: ModelConfig, shardings=None):
+    """Random parameters for `cfg`, built by ONE jitted program: XLA fuses
+    each initializer's f32 normal draw into its cast to the param dtype, so
+    no f32 copy of a whole (layer-stacked) weight is ever live — at full
+    width that copy alone would outgrow a chip's HBM. `shardings` (a
+    pytree of jax.sharding.Sharding matching the params) places every leaf
+    directly on its devices; None leaves placement to jit."""
+    if shardings is None:
+        return _init_params_jit(key, cfg)
+    return jax.jit(_init_params, static_argnums=1,
+                   out_shardings=shardings)(key, cfg)
+
+
+def _init_params(key, cfg: ModelConfig):
     keys = jax.random.split(key, 4)
     params: Dict[str, Any] = {"embed": layers.embed_init(keys[0], cfg)}
 
@@ -186,6 +209,9 @@ def init_params(key, cfg: ModelConfig):
             keys[3], (cfg.padded_vocab_size, cfg.d_model), jnp.float32)
             * layers.INIT_STD).astype(jnp.dtype(cfg.param_dtype))}
     return params
+
+
+_init_params_jit = jax.jit(_init_params, static_argnums=1)
 
 
 def init_cache(cfg: ModelConfig, batch: int, context: int,

@@ -1,4 +1,4 @@
 from repro.parallel.axes import (  # noqa: F401
     DEFAULT_RULES, axis_rules, current_mesh, current_rules, logical_to_spec,
-    shard, shard_map,
+    shard,
 )

@@ -15,18 +15,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma=None):
-    """jax.shard_map across JAX versions: newer releases expose it at the
-    top level with `check_vma`; older ones live in jax.experimental with
-    `check_rep` (same meaning)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
 Axis = Union[None, str, Tuple[str, ...]]
 
 # Default logical->physical rules for the production 2-D/3-D meshes.

@@ -98,7 +98,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, *,
         return outputs
 
     stacked_spec = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = pax.shard_map(per_device, mesh=mesh,
+    fn = jax.shard_map(per_device, mesh=mesh,
                        in_specs=(stacked_spec, x_spec), out_specs=x_spec,
                        check_vma=False)
     return fn(stage_params, x_micro)

@@ -95,17 +95,8 @@ class RooflineReport:
         return d
 
 
-def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() across JAX versions: newer releases return
-    the dict directly, older ones a one-element list of dicts."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def component_cost(compiled) -> ComponentCost:
-    ca = cost_dict(compiled)
+    ca = compiled.cost_analysis()
     ops = HLO.parse_collectives(compiled.as_text())
     summary = HLO.collective_summary(ops)
     return ComponentCost(

@@ -40,7 +40,7 @@ from repro.models import model as M
 
 def make_prefill_step(cfg: ModelConfig,
                       settings: Optional[M.ModelSettings] = None):
-    settings = settings or M.ModelSettings()
+    settings = settings or M.serving_settings()
     psettings = dataclasses.replace(settings, build_cache=True)
 
     def prefill_step(params, tokens, context: int, prefix_embeds=None):
@@ -55,7 +55,7 @@ def make_prefill_step(cfg: ModelConfig,
 
 def make_decode_step(cfg: ModelConfig,
                      settings: Optional[M.ModelSettings] = None):
-    settings = settings or M.ModelSettings()
+    settings = settings or M.serving_settings()
 
     def decode_step(params, tokens, positions, cache, context: int):
         logits, new_cache, _ = M.apply(params, cfg, tokens,
@@ -123,7 +123,7 @@ def make_slot_prefill_step(cfg: ModelConfig,
     donated pool cache. Returns (last-token logits [1, V], new pool). One
     compile per distinct prompt length (bucketed traces keep that small);
     the decode step stays a single compile at pool width."""
-    settings = settings or M.ModelSettings()
+    settings = settings or M.serving_settings()
     psettings = dataclasses.replace(settings, build_cache=True)
 
     def prefill_into_slot(params, tokens, slot, pool, context: int):
@@ -140,7 +140,7 @@ def make_batch_prefill_step(cfg: ModelConfig,
     padding rows filled with dummy prompts) and scatter each row into pool
     slot `slots[w]` (index >= W drops the row). One compile per prompt
     bucket p, shared by every admission tick that hits the bucket."""
-    settings = settings or M.ModelSettings()
+    settings = settings or M.serving_settings()
     psettings = dataclasses.replace(settings, build_cache=True)
 
     def prefill_into_slots(params, tokens, slots, pool, context: int):
@@ -174,7 +174,7 @@ def is_paged_block(blk, context: int) -> bool:
 
 def init_paged_pool(cfg: ModelConfig, n_lanes: int, n_blocks: int,
                     block: int, context: int, abstract: bool = False,
-                    kv_quant: str = "none"):
+                    kv_quant: str = "none", mesh=None):
     """The paged serving pool: paged layers get block-pool leaves (shared
     across lanes), everything else a per-lane cache like init_slot_pool.
     `context` must be a multiple of `block` (the executor rounds up).
@@ -184,7 +184,10 @@ def init_paged_pool(cfg: ModelConfig, n_lanes: int, n_blocks: int,
     head) f32 absmax scales in sibling "ks"/"vs" leaves. The pool is
     self-describing: read/write paths pick the codec off the leaf dtypes
     (attention.paged_quant_kind), so a quantized pool can never be
-    misread as fp."""
+    misread as fp.
+
+    With a `mesh`, the pool is materialized straight into its shardings
+    (pool_shardings): paged leaves split KV heads over the mesh."""
     if context % block:
         raise ValueError(f"paged pool context {context} must be a multiple "
                          f"of the kv block size {block}")
@@ -232,12 +235,46 @@ def init_paged_pool(cfg: ModelConfig, n_lanes: int, n_blocks: int,
             one)
         return stack if abstract else jax.tree.map(_materialize, stack)
 
+    if mesh is not None and not abstract:
+        shapes = init_paged_pool(cfg, n_lanes, n_blocks, block, context,
+                                 abstract=True, kv_quant=kv_quant)
+        return jax.jit(lambda: jax.tree.map(_materialize, shapes),
+                       out_shardings=pool_shardings(shapes, mesh))()
     pool = {"units": [stacked(blk) for blk in cfg.unit], "tail": []}
     for blk in cfg.tail:
         one = one_cache(blk)
         pool["tail"].append(one if abstract
                             else jax.tree.map(_materialize, one))
     return pool
+
+
+# logical axes of one layer's paged pool leaves (layer-stacked leaves lead
+# with "layers")
+_PAGED_AXES = {"kb": (None, None, "kv_heads", None),
+               "vb": (None, None, "kv_heads", None),
+               "ks": (None, None, "kv_heads"),
+               "vs": (None, None, "kv_heads"),
+               "pos": (None, None)}
+
+
+def pool_shardings(pool, mesh):
+    """NamedShardings of a paged serving pool on `mesh` under the ambient
+    axis rules: block-pool leaves split their KV-head dim (the attention
+    kernels run per head shard), per-lane leaves are replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.parallel import axes as pax
+
+    def place(P, stacked):
+        lead = ("layers",) if stacked else ()
+        if not _is_paged_leaf(P):
+            return jax.tree.map(
+                lambda v: NamedSharding(mesh, PartitionSpec()), P)
+        return {k: NamedSharding(mesh, pax.logical_to_spec(
+            lead + _PAGED_AXES[k], mesh=mesh, shape=v.shape))
+            for k, v in P.items()}
+
+    return {"units": [place(P, True) for P in pool["units"]],
+            "tail": [place(P, False) for P in pool["tail"]]}
 
 
 def write_paged_prefill(cfg: ModelConfig, pool, one, lanes, tables,
@@ -300,7 +337,7 @@ def make_paged_prefill_step(cfg: ModelConfig,
                             settings: Optional[M.ModelSettings] = None):
     """Batched prefill into the paged pool: tokens [W, p], lanes [W],
     tables [W, context // block]. One compile per prompt bucket."""
-    settings = settings or M.ModelSettings()
+    settings = settings or M.serving_settings()
     psettings = dataclasses.replace(settings, build_cache=True)
 
     def prefill_paged(params, tokens, lanes, tables, pool, context: int):
@@ -317,7 +354,7 @@ def make_paged_decode_step(cfg: ModelConfig,
                            settings: Optional[M.ModelSettings] = None):
     """One batched decode tick through the block tables: a single compile
     at lane width regardless of pool occupancy."""
-    settings = settings or M.ModelSettings()
+    settings = settings or M.serving_settings()
 
     def decode_paged(params, tokens, positions, tables, pool, context: int):
         logits, new_pool, aux = M.apply(params, cfg, tokens,
@@ -383,7 +420,7 @@ def make_compact_decode_step(cfg: ModelConfig,
     per (w, table-width) bucket, so each touched bucket costs one compile
     and a tick with 3 active lanes stops paying for the padded remainder
     of the pool."""
-    settings = settings or M.ModelSettings()
+    settings = settings or M.serving_settings()
 
     def decode_compact(params, tokens, positions, tables, lane_ids, pool,
                        context: int):
@@ -408,7 +445,7 @@ def make_chunk_prefill_step(cfg: ModelConfig,
     last-valid-position logits (meaningful for rows whose chunk completes
     the prompt) and the updated pool. One compile per (width bucket,
     table width); C is fixed by the engine's chunk size."""
-    settings = settings or M.ModelSettings()
+    settings = settings or M.serving_settings()
     psettings = dataclasses.replace(settings, build_cache=True)
 
     def prefill_chunk(params, tokens, positions, tables, lane_ids, pool,
@@ -463,9 +500,10 @@ def clear_pool(pool):
     re-prefill anyway, so a preempted replica hands its existing device
     buffers to the restored engine instead of paying a fresh allocation."""
     def f(x):
+        # fresh buffers on the same devices and shardings as the old ones
         if hasattr(x, "dtype") and x.dtype == jnp.int32:
-            return jnp.full(x.shape, -1, x.dtype)
-        return jnp.zeros(x.shape, x.dtype)
+            return jnp.full_like(x, -1, device=x.sharding)
+        return jnp.zeros_like(x, device=x.sharding)
 
     return jax.tree.map(f, pool)
 
@@ -516,7 +554,8 @@ def serve_steps(cfg: ModelConfig,
     sharding context): repeated greedy_generate calls (tests, examples)
     reuse the compiled steps instead of re-tracing per call. `context` is
     static and the decode cache is donated in place."""
-    return _jitted_serve_steps(cfg, settings, "plain", _sharding_ctx_key())
+    return _jitted_serve_steps(cfg, settings or M.serving_settings(),
+                               "plain", _sharding_ctx_key())
 
 
 def slot_serve_steps(cfg: ModelConfig,
@@ -526,7 +565,8 @@ def slot_serve_steps(cfg: ModelConfig,
     successive executors (e.g. the serve driver's --policy both runs)
     share compiled steps instead of paying the whole compile set again.
     Pool arguments are donated."""
-    return _jitted_serve_steps(cfg, settings, "slot", _sharding_ctx_key())
+    return _jitted_serve_steps(cfg, settings or M.serving_settings(),
+                               "slot", _sharding_ctx_key())
 
 
 def paged_serve_steps(cfg: ModelConfig,
@@ -538,7 +578,8 @@ def paged_serve_steps(cfg: ModelConfig,
     prefill compiles once per prompt bucket (padded to lane width) and
     chunk-prefill once per touched width bucket at the fixed chunk
     length."""
-    return _jitted_serve_steps(cfg, settings, "paged", _sharding_ctx_key())
+    return _jitted_serve_steps(cfg, settings or M.serving_settings(),
+                               "paged", _sharding_ctx_key())
 
 
 def greedy_generate(params, cfg: ModelConfig, prompt_tokens, n_steps: int,

@@ -36,23 +36,13 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
+from repro.parallel import axes as pax
 from repro.runtime import serve_step as SS
 
 
-def _compile_count(fn) -> Optional[int]:
-    try:
-        return int(fn._cache_size())
-    except AttributeError:          # older jax: no cache-size probe
-        return None
-
-
-def _sum_compile_counts(*fns) -> Optional[int]:
-    """Sum per-step compile counts, propagating 'unknown' (None) instead of
-    arithmetic on sentinels."""
-    counts = [_compile_count(fn) for fn in fns]
-    if any(c is None for c in counts):
-        return None
-    return sum(counts)
+def _compile_count(fn) -> int:
+    """Compiled variants a jitted step holds (one per input signature)."""
+    return int(fn._cache_size())
 
 
 def _pad_token(cfg: ModelConfig) -> int:
@@ -164,10 +154,9 @@ class JaxExecutor:
     def compile_counts(self) -> dict:
         """Compiled-variant counts of the serving steps (prefill: one per
         prompt-length bucket; decode: one) — the driver reports them so
-        'every compile served traffic' is checkable. None = unknown (older
-        jax exposes no cache-size probe)."""
+        'every compile served traffic' is checkable."""
         single, batch, decode_step = self._steps()
-        return {"prefill": _sum_compile_counts(batch, single),
+        return {"prefill": _compile_count(batch) + _compile_count(single),
                 "decode": _compile_count(decode_step)}
 
 
@@ -218,7 +207,7 @@ class PagedJaxExecutor:
             # `track_mass=True` alone pays the accounting without a
             # standing retention cap, for engines whose degradation
             # ladder may engage `bend_retain` mid-run.
-            base = settings or M.ModelSettings()
+            base = settings or M.serving_settings()
             settings = dataclasses.replace(
                 base, attn=dataclasses.replace(base.attn, track_mass=True))
         self.settings = settings
@@ -249,9 +238,13 @@ class PagedJaxExecutor:
         # still refuses prefix_share here — shared prefix blocks hold
         # attention KV only, not the recurrent state at the boundary
         self.has_recurrent = any(not b.is_attn for b in cfg.blocks())
-        self.pool = SS.init_paged_pool(cfg, self.n_lanes, self.n_blocks + 1,
-                                       kv_block, self.context,
-                                       kv_quant=self.kv_quant)
+        # under a multi-device mesh the pool is placed straight into its
+        # KV-head shardings (the jitted steps then keep it there)
+        mesh = pax.current_mesh()
+        self.pool = SS.init_paged_pool(
+            cfg, self.n_lanes, self.n_blocks + 1, kv_block, self.context,
+            kv_quant=self.kv_quant,
+            mesh=mesh if mesh is not None and mesh.size > 1 else None)
         self.prefills = 0
         self.decodes = 0
         self.chunk_calls = 0

@@ -136,12 +136,10 @@ from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.optim.compress import compressed_psum
-from repro.parallel import shard_map
-
 mesh = make_mesh((8,), ("data",))
 x = jnp.arange(64.0).reshape(8, 8) / 7.0
 
-@partial(shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
+@partial(jax.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
 def f(xs):
     key = jax.random.PRNGKey(0)
     return compressed_psum(xs, "data", key)
