@@ -23,6 +23,7 @@ cuts a published-width model to N unit repeats so it fits one chip.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 from typing import Optional
 
@@ -194,6 +195,11 @@ def main(argv=None, out: Optional[dict] = None):
     ap.add_argument("--forbid-plan-compiles", action="store_true",
                     help="fail if planning attempts an XLA compile (CI "
                          "guard; incompatible with --backend compile)")
+    ap.add_argument("--trace-dir", default="",
+                    help="write a profiler trace of the serving runs "
+                         "here (jax.profiler.trace): the engine's and the "
+                         "paged executor's host spans on the device's "
+                         "timeline")
     args = ap.parse_args(argv)
 
     if args.forbid_plan_compiles and args.backend == "compile":
@@ -364,7 +370,9 @@ def main(argv=None, out: Optional[dict] = None):
                 else [args.policy])
     reports = []
     failures = []
-    with mesh, axis_rules(strategy.rules(), mesh=mesh):
+    profile = (jax.profiler.trace(args.trace_dir) if args.trace_dir
+               else contextlib.nullcontext())
+    with profile, mesh, axis_rules(strategy.rules(), mesh=mesh):
         for policy in policies:
             chunk = 0
             if args.kv == "paged":

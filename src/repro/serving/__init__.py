@@ -1,8 +1,9 @@
 """Memory-governed serving: WSMC capacity prediction drives continuous
 batching over a slotted KV pool.
 
-`trace`, `engine` and `faults` are jax-free (the scheduler is a
-deterministic state machine and the chaos harness injects into it); the
+`trace`, `engine` and `faults` touch no device (the scheduler is a
+deterministic state machine and the chaos wrappers inject into it; the
+engine uses jax only for its profiler span); the
 jax-backed executor lives in `repro.serving.executor` and is imported
 lazily so planning/metrics code never touches device state.
 """

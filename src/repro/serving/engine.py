@@ -1,8 +1,9 @@
 """The serving engine: WSMC-governed continuous batching over a slotted
 KV pool.
 
-The scheduler is deliberately jax-free: it speaks to the model through an
-executor protocol (``prefill_batch(slots, prompts, tables=None) ->
+The scheduler is deliberately device-free (jax only marks each tick as
+an `engine.step` span in the profiler's trace): it speaks to the model
+through an executor protocol (``prefill_batch(slots, prompts, tables=None) ->
 first_tokens``, ``decode(tokens, positions, tables=None, lanes=None) ->
 next_tokens``, ``fresh_blocks(ids)``, plus the optional
 ``decode_width(n_active)`` width probe and ``prefill_chunks(lanes,
@@ -63,7 +64,10 @@ from __future__ import annotations
 import collections
 import dataclasses
 import json
+from functools import partial
 from typing import Deque, Dict, List, Optional, Sequence, Set, Tuple
+
+from jax.profiler import annotate_function
 
 from repro.serving.trace import LengthStats, Request
 
@@ -117,7 +121,7 @@ class LedgerCorruption(RuntimeError):
 
 
 class BlockAllocator:
-    """jax-free free-list allocator over the paged KV block pool.
+    """Device-free free-list allocator over the paged KV block pool.
 
     Physical ids run 1..n_blocks (id 0 is the executor's scratch block for
     inactive decode lanes — never handed out). Admission reserves a
@@ -742,7 +746,7 @@ class ServeReport:
 
 
 class ScriptedExecutor:
-    """Deterministic jax-free executor: closed-form token functions stand in
+    """Deterministic device-free executor: closed-form token functions stand in
     for the model so the scheduler core (admission, claim/free, metrics,
     eviction, prefix sharing) can be pinned by the hermetic test tier and
     compared across policies without a single compile.
@@ -761,9 +765,7 @@ class ScriptedExecutor:
                  buckets: Optional[Sequence[int]] = None):
         self.vocab_size = vocab_size
         self.buckets = tuple(sorted(buckets)) if buckets else None
-        self.prefills = 0
         self.prefill_batches = 0
-        self.decodes = 0
         self.chunk_calls = 0
         self.chunk_tokens = 0
         self.tick_widths: List[int] = []
@@ -780,7 +782,6 @@ class ScriptedExecutor:
         return (17 * last + 7 * pos + 13) % self.vocab_size
 
     def prefill(self, slot: int, prompt: Sequence[int]) -> int:
-        self.prefills += 1
         return self._token_at(prompt[-1], len(prompt) - 1)
 
     def prefill_batch(self, slots: Sequence[int],
@@ -812,7 +813,6 @@ class ScriptedExecutor:
             self.chunk_tokens += len(chunks[j])
             if final is not None and final[j]:
                 start, acc = self._partial.pop(lane)
-                self.prefills += 1
                 out.append(self._token_at(acc[-1], start + len(acc) - 1))
             else:
                 out.append(0)
@@ -833,7 +833,6 @@ class ScriptedExecutor:
     def decode(self, tokens: Sequence[int], positions: Sequence[int],
                tables: Optional[Sequence[Sequence[int]]] = None,
                lanes: Optional[Sequence[int]] = None) -> List[int]:
-        self.decodes += 1
         n_active = len(lanes) if lanes is not None else len(tokens)
         width = self.decode_width(n_active)
         self.tick_widths.append(width if width is not None else len(tokens))
@@ -1622,11 +1621,13 @@ class Engine:
             self.stats.observe(len(a.req.prompt),
                                len(a.req.prompt) + a.req.max_new - 1)
 
+    @partial(annotate_function, name="engine.step")
     def _step(self, st: _RunState) -> None:
         """One engine tick: fault-plan events → deadline sweep → arrivals
         (shed under rung 4) → ladder update → proactive eviction →
         admission / prefill chunks / batched decode (all executor work
-        skipped while retry backoff is armed) → retention → audit."""
+        skipped while retry backoff is armed) → retention → audit. The
+        tick is one `engine.step` span in the profiler's trace."""
         alloc = self.allocator
         ev0 = self._evictions
         canc0 = len(st.cancellations)

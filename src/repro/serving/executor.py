@@ -25,14 +25,25 @@ recompute into an owned block), and an EVICTED request re-prefills
 `prompt + already-emitted tokens` from scratch into freshly allocated
 blocks. No executor state survives an eviction; everything is the block
 tables.
+
+The paged executor marks its host work with fixed-name spans in the
+profiler's trace (`jax.profiler.TraceAnnotation`, on the same timeline as
+the device's programs): `executor.decode` / `.chunk` / `.prefill` /
+`.reset` cover one call, and inside decode, chunk and prefill
+`executor.<kind>.pack` builds the numpy inputs, `.launch` copies them to
+the device and enqueues the jitted step, and `.pull` reads the next tokens
+back (waiting for the device). With no profiler session they cost under a
+microsecond each.
 """
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
@@ -56,16 +67,16 @@ def _pad_token(cfg: ModelConfig) -> int:
 
 def _pad_batch(width: int, slots: Sequence[int],
                prompts: Sequence[Sequence[int]], pad_token: int):
-    """Pack a same-length admission group into pool-width arrays: padding
-    rows carry dummy prompts (`pad_token`) and index `width` — out of
-    bounds, so the prefill scatter drops them (mode='drop')."""
+    """Pack a same-length admission group into pool-width numpy arrays:
+    padding rows carry dummy prompts (`pad_token`) and index `width` — out
+    of bounds, so the prefill scatter drops them (mode='drop')."""
     p = len(prompts[0])
     toks = np.full((width, p), pad_token, np.int32)
     idx = np.full((width,), width, np.int32)
     for i, (s, pr) in enumerate(zip(slots, prompts)):
         toks[i] = pr
         idx[i] = s
-    return jnp.asarray(toks), jnp.asarray(idx)
+    return toks, idx
 
 
 def _pow2_buckets(n: int) -> tuple:
@@ -104,8 +115,6 @@ class JaxExecutor:
         self.n_slots = int(n_slots)
         self.context = int(context)
         self.pool = SS.init_slot_pool(cfg, self.n_slots, self.context)
-        self.prefills = 0
-        self.decodes = 0
 
     def _steps(self):
         # fetched per call: memoized on (cfg, settings, ambient sharding
@@ -118,7 +127,6 @@ class JaxExecutor:
         tokens = jnp.asarray(list(prompt), jnp.int32)[None, :]
         logits, self.pool = prefill_step(self.params, tokens, slot,
                                          self.pool, context=self.context)
-        self.prefills += 1
         return int(jnp.argmax(logits[0], axis=-1))
 
     def prefill_batch(self, slots: Sequence[int],
@@ -130,9 +138,9 @@ class JaxExecutor:
         _, batch_step, _ = self._steps()
         toks, slot_arr = _pad_batch(self.n_slots, slots, prompts,
                                     _pad_token(self.cfg))
-        logits, self.pool = batch_step(self.params, toks, slot_arr,
-                                       self.pool, context=self.context)
-        self.prefills += len(slots)        # per-request, like the engine
+        logits, self.pool = batch_step(self.params, jnp.asarray(toks),
+                                       jnp.asarray(slot_arr), self.pool,
+                                       context=self.context)
         out = np.asarray(jnp.argmax(logits, axis=-1))
         return [int(out[i]) for i in range(len(slots))]
 
@@ -148,7 +156,6 @@ class JaxExecutor:
         p = jnp.asarray(list(positions), jnp.int32)
         logits, self.pool = decode_step(self.params, t, p, self.pool,
                                         context=self.context)
-        self.decodes += 1
         return np.asarray(jnp.argmax(logits, axis=-1)).astype(int).tolist()
 
     def compile_counts(self) -> dict:
@@ -245,8 +252,6 @@ class PagedJaxExecutor:
             cfg, self.n_lanes, self.n_blocks + 1, kv_block, self.context,
             kv_quant=self.kv_quant,
             mesh=mesh if mesh is not None and mesh.size > 1 else None)
-        self.prefills = 0
-        self.decodes = 0
         self.chunk_calls = 0
         # lane -> per-logical-block attention mass from the LAST decode
         # tick (only populated when kv_retain or track_mass enables the
@@ -267,20 +272,26 @@ class PagedJaxExecutor:
             out[i, :len(tbl)] = tbl
         return out
 
+    @partial(annotate_function, name="executor.prefill")
     def prefill_batch(self, lanes: Sequence[int],
                       prompts: Sequence[Sequence[int]],
                       tables: Sequence[Sequence[int]]) -> List[int]:
         prefill_step = self._steps()[0]
         w = self.n_lanes
-        toks, lane_arr = _pad_batch(w, lanes, prompts, _pad_token(self.cfg))
-        tbl = self._table_array(list(tables) + [[]] * (w - len(tables)), w)
-        logits, self.pool = prefill_step(self.params, toks, lane_arr,
-                                         jnp.asarray(tbl), self.pool,
-                                         context=self.context)
-        self.prefills += len(lanes)        # per-request, like the engine
-        out = np.asarray(jnp.argmax(logits, axis=-1))
-        return [int(out[i]) for i in range(len(lanes))]
+        with TraceAnnotation("executor.prefill.pack"):
+            toks, lane_arr = _pad_batch(w, lanes, prompts,
+                                        _pad_token(self.cfg))
+            tbl = self._table_array(
+                list(tables) + [[]] * (w - len(tables)), w)
+        with TraceAnnotation("executor.prefill.launch"):
+            logits, self.pool = prefill_step(
+                self.params, jnp.asarray(toks), jnp.asarray(lane_arr),
+                jnp.asarray(tbl), self.pool, context=self.context)
+        with TraceAnnotation("executor.prefill.pull"):
+            out = np.asarray(jnp.argmax(logits, axis=-1))
+            return [int(out[i]) for i in range(len(lanes))]
 
+    @partial(annotate_function, name="executor.reset")
     def fresh_blocks(self, ids: Sequence[int]) -> None:
         """Invalidate re-linked physical blocks (pos = -1) before decode
         reads them through a new owner's table. Padded to a multiple of
@@ -310,35 +321,41 @@ class PagedJaxExecutor:
             return self.n_lanes
         return _cover(max(int(n_active), 1), self.lane_buckets)
 
+    @partial(annotate_function, name="executor.decode")
     def decode(self, tokens: Sequence[int], positions: Sequence[int],
                tables: Sequence[Sequence[int]], lanes=None) -> List[int]:
         if self.compact and lanes is not None:
             return self._decode_compact(tokens, positions, tables, lanes)
         decode_step = self._steps()[1]
-        if lanes is not None:
-            # Full-width decode still computes every lane row; rows NOT in
-            # `lanes` (empty slots, lanes mid-chunk-prefill) are made INERT
-            # (pos -1, empty table) so their write lands in the scratch
-            # block / is dropped instead of clobbering live KV through a
-            # mid-prefill lane's real block table.
-            act = set(int(i) for i in lanes)
-            pad = _pad_token(self.cfg)
-            tokens = [t if i in act else pad for i, t in enumerate(tokens)]
-            positions = [p if i in act else -1
-                         for i, p in enumerate(positions)]
-            tables = [t if i in act else [] for i, t in enumerate(tables)]
-        t = jnp.asarray(list(tokens), jnp.int32)[:, None]
-        p = jnp.asarray(list(positions), jnp.int32)
-        tbl = jnp.asarray(self._table_array(tables, self.n_lanes))
-        logits, self.pool, mass = decode_step(self.params, t, p, tbl,
-                                              self.pool,
-                                              context=self.context)
-        self.decodes += 1
-        if mass is not None:
-            m = np.asarray(mass)
-            act = lanes if lanes is not None else range(len(m))
-            self._last_mass = {int(i): m[int(i)] for i in act}
-        return np.asarray(jnp.argmax(logits, axis=-1)).astype(int).tolist()
+        with TraceAnnotation("executor.decode.pack"):
+            if lanes is not None:
+                # Full-width decode still computes every lane row; rows NOT
+                # in `lanes` (empty slots, lanes mid-chunk-prefill) are
+                # made INERT (pos -1, empty table) so their write lands in
+                # the scratch block / is dropped instead of clobbering live
+                # KV through a mid-prefill lane's real block table.
+                act = set(int(i) for i in lanes)
+                pad = _pad_token(self.cfg)
+                tokens = [t if i in act else pad
+                          for i, t in enumerate(tokens)]
+                positions = [p if i in act else -1
+                             for i, p in enumerate(positions)]
+                tables = [t if i in act else []
+                          for i, t in enumerate(tables)]
+            t = np.asarray(list(tokens), np.int32)[:, None]
+            p = np.asarray(list(positions), np.int32)
+            tbl = self._table_array(tables, self.n_lanes)
+        with TraceAnnotation("executor.decode.launch"):
+            logits, self.pool, mass = decode_step(
+                self.params, jnp.asarray(t), jnp.asarray(p),
+                jnp.asarray(tbl), self.pool, context=self.context)
+        with TraceAnnotation("executor.decode.pull"):
+            if mass is not None:
+                m = np.asarray(mass)
+                act = lanes if lanes is not None else range(len(m))
+                self._last_mass = {int(i): m[int(i)] for i in act}
+            return np.asarray(jnp.argmax(logits, axis=-1)).astype(
+                int).tolist()
 
     def _decode_compact(self, tokens, positions, tables, lanes) -> List[int]:
         """Pack the active lanes into the smallest covering bucket and run
@@ -346,37 +363,39 @@ class PagedJaxExecutor:
         per-lane write-back is dropped) and an all -1 table (they read and
         write only the scratch block)."""
         compact_step = self._steps()[3]
-        w = self.decode_width(len(lanes))
-        mb = _cover(max((len(tables[i]) for i in lanes), default=1),
-                    self.table_buckets)
-        t = np.zeros((w, 1), np.int32)
-        p = np.zeros((w,), np.int32)
-        lane_arr = np.full((w,), self.n_lanes, np.int32)
-        tbl = np.full((w, mb), -1, np.int32)
-        for j, i in enumerate(lanes):
-            t[j, 0] = tokens[i]
-            p[j] = positions[i]
-            lane_arr[j] = i
-            if len(tables[i]) > mb:
-                raise ValueError(f"lane {i}: table of {len(tables[i])} "
-                                 f"blocks exceeds bucketed width {mb}")
-            tbl[j, :len(tables[i])] = tables[i]
-        logits, self.pool, mass = compact_step(self.params, jnp.asarray(t),
-                                               jnp.asarray(p),
-                                               jnp.asarray(tbl),
-                                               jnp.asarray(lane_arr),
-                                               self.pool,
-                                               context=self.context)
-        self.decodes += 1
-        if mass is not None:
-            m = np.asarray(mass)
-            self._last_mass = {int(i): m[j] for j, i in enumerate(lanes)}
-        out = np.asarray(jnp.argmax(logits, axis=-1))
-        res = [0] * self.n_lanes
-        for j, i in enumerate(lanes):
-            res[i] = int(out[j])
-        return res
+        with TraceAnnotation("executor.decode.pack"):
+            w = self.decode_width(len(lanes))
+            mb = _cover(max((len(tables[i]) for i in lanes), default=1),
+                        self.table_buckets)
+            t = np.zeros((w, 1), np.int32)
+            p = np.zeros((w,), np.int32)
+            lane_arr = np.full((w,), self.n_lanes, np.int32)
+            tbl = np.full((w, mb), -1, np.int32)
+            for j, i in enumerate(lanes):
+                t[j, 0] = tokens[i]
+                p[j] = positions[i]
+                lane_arr[j] = i
+                if len(tables[i]) > mb:
+                    raise ValueError(f"lane {i}: table of {len(tables[i])} "
+                                     f"blocks exceeds bucketed width {mb}")
+                tbl[j, :len(tables[i])] = tables[i]
+        with TraceAnnotation("executor.decode.launch"):
+            logits, self.pool, mass = compact_step(
+                self.params, jnp.asarray(t), jnp.asarray(p),
+                jnp.asarray(tbl), jnp.asarray(lane_arr), self.pool,
+                context=self.context)
+        with TraceAnnotation("executor.decode.pull"):
+            if mass is not None:
+                m = np.asarray(mass)
+                self._last_mass = {int(i): m[j]
+                                   for j, i in enumerate(lanes)}
+            out = np.asarray(jnp.argmax(logits, axis=-1))
+            res = [0] * self.n_lanes
+            for j, i in enumerate(lanes):
+                res[i] = int(out[j])
+            return res
 
+    @partial(annotate_function, name="executor.chunk")
     def prefill_chunks(self, lanes: Sequence[int],
                        chunks: Sequence[Sequence[int]],
                        starts: Sequence[int],
@@ -390,33 +409,35 @@ class PagedJaxExecutor:
         if not self.chunk:
             raise RuntimeError("executor built with chunk=0")
         chunk_step = self._steps()[4]
-        w = _cover(len(lanes), self.lane_buckets)
         C = self.chunk
-        tbls = [list(t) for t in (tables if tables is not None else
-                                  [[]] * len(lanes))]
-        mb = _cover(max((len(t) for t in tbls), default=1),
-                    self.table_buckets)
-        toks = np.full((w, C), _pad_token(self.cfg), np.int32)
-        pos = np.full((w, C), -1, np.int32)
-        lane_arr = np.full((w,), self.n_lanes, np.int32)
-        tbl = self._table_array(tbls + [[]] * (w - len(tbls)), w, width=mb)
-        for j, lane in enumerate(lanes):
-            c = list(chunks[j])
-            if not 0 < len(c) <= C:
-                raise ValueError(f"lane {lane}: chunk of {len(c)} tokens "
-                                 f"vs chunk size {C}")
-            toks[j, :len(c)] = c
-            pos[j, :len(c)] = starts[j] + np.arange(len(c))
-            lane_arr[j] = lane
-        logits, self.pool = chunk_step(self.params, jnp.asarray(toks),
-                                       jnp.asarray(pos), jnp.asarray(tbl),
-                                       jnp.asarray(lane_arr), self.pool,
-                                       context=self.context)
+        with TraceAnnotation("executor.chunk.pack"):
+            w = _cover(len(lanes), self.lane_buckets)
+            tbls = [list(t) for t in (tables if tables is not None else
+                                      [[]] * len(lanes))]
+            mb = _cover(max((len(t) for t in tbls), default=1),
+                        self.table_buckets)
+            toks = np.full((w, C), _pad_token(self.cfg), np.int32)
+            pos = np.full((w, C), -1, np.int32)
+            lane_arr = np.full((w,), self.n_lanes, np.int32)
+            tbl = self._table_array(tbls + [[]] * (w - len(tbls)), w,
+                                    width=mb)
+            for j, lane in enumerate(lanes):
+                c = list(chunks[j])
+                if not 0 < len(c) <= C:
+                    raise ValueError(f"lane {lane}: chunk of {len(c)} "
+                                     f"tokens vs chunk size {C}")
+                toks[j, :len(c)] = c
+                pos[j, :len(c)] = starts[j] + np.arange(len(c))
+                lane_arr[j] = lane
+        with TraceAnnotation("executor.chunk.launch"):
+            logits, self.pool = chunk_step(
+                self.params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(tbl), jnp.asarray(lane_arr), self.pool,
+                context=self.context)
         self.chunk_calls += 1
-        if final is not None:
-            self.prefills += sum(bool(f) for f in final)
-        out = np.asarray(jnp.argmax(logits, axis=-1))
-        return [int(out[j]) for j in range(len(lanes))]
+        with TraceAnnotation("executor.chunk.pull"):
+            out = np.asarray(jnp.argmax(logits, axis=-1))
+            return [int(out[j]) for j in range(len(lanes))]
 
     def block_masses(self) -> Dict[int, np.ndarray]:
         """Per-lane attention mass over the lane's logical blocks from the

@@ -1,6 +1,6 @@
 """Capacity bending, hermetic tier: per-block KV quantization codecs
 (int8 / nibble-packed int4 with per-row absmax scales), block-granular
-retention on the jax-free allocator and scripted engine (evicted blocks
+retention on the device-free allocator and scripted engine (evicted blocks
 reusable, never double-freed, shared prefix blocks immune), the bending
 knobs of `serving_space`, and `plan_serving`'s minimum-agreement gate on
 the quality/capacity frontier. Token parity of the REAL bent executor

@@ -1,6 +1,6 @@
 """Paged KV block pool, hermetic tier: block-granular capacity inversion
 (predictor.serving_block_capacity), the paged planning mode of
-plan_serving, the jax-free BlockAllocator, the paged engine scheduling
+plan_serving, the device-free BlockAllocator, the paged engine scheduling
 core (admission by actual footprint, block reuse, determinism), the
 engine-level batched prefill, and eval_shape pins of the paged pool steps
 — all with ZERO XLA compiles. Token parity of the real paged executor
@@ -189,7 +189,7 @@ def test_serving_space_kv_block_knob():
     assert sizes == {64}                     # oversize block filtered out
 
 
-# --- BlockAllocator: the jax-free free list ---------------------------------
+# --- BlockAllocator: the device-free free list ------------------------------
 
 def test_allocator_alloc_free_reuse():
     a = BlockAllocator(6, block_size=4)

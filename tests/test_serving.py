@@ -1,6 +1,6 @@
 """Memory-governed serving engine, hermetic tier: the capacity inversion
 (predictor.serving_capacity), the max-concurrency planner (plan_serving),
-and the jax-free scheduler core (admission bound, queueing, continuous-vs-
+and the device-free scheduler core (admission bound, queueing, continuous-vs-
 static occupancy) — all with ZERO XLA compiles. Token parity of the real
 executor against greedy_generate lives in the slow tier (test_serve.py)."""
 import dataclasses
