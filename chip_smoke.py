@@ -145,11 +145,15 @@ def serve_phase(argv, devices, n_requests):
 # Phase 2: compiled kernels vs the jnp paths, real head shape
 # ---------------------------------------------------------------------------
 
+LAYER = 1                       # the layer of the stacked pool the checks use
+
+
 def _paged_case(quant, seed=0, b=4, bs=16, m_blocks=8, n_blocks=64,
                 K=8, G=4, hd=128, C=32):
-    """A pool holding per-lane history written by the jnp writer, block
-    tables over disjoint physical blocks (0 is scratch), decode cursors,
-    and a prompt chunk per lane (the last lane's chunk is short)."""
+    """A two-layer stacked pool whose layer `LAYER` holds per-lane history
+    written by the jnp writer, block tables over disjoint physical blocks
+    (0 is scratch), decode cursors, and a prompt chunk per lane (the last
+    lane's chunk is short)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -159,13 +163,14 @@ def _paged_case(quant, seed=0, b=4, bs=16, m_blocks=8, n_blocks=64,
     bf = jnp.bfloat16
     starts = [37, 0, 64, 5]                  # history length per lane
     n_valid = [C, C, C, C // 2 + 3]
-    cache = {"pos": jnp.full((n_blocks, bs), -1, jnp.int32)}
+    n = 2
+    cache = {"pos": jnp.full((n, n_blocks, bs), -1, jnp.int32)}
     pool_dt = {"none": bf, "int8": jnp.int8}[quant]
-    cache["kb"] = jnp.zeros((n_blocks, bs, K, hd), pool_dt)
-    cache["vb"] = jnp.zeros((n_blocks, bs, K, hd), pool_dt)
+    cache["kb"] = jnp.zeros((n, n_blocks, bs, K, hd), pool_dt)
+    cache["vb"] = jnp.zeros((n, n_blocks, bs, K, hd), pool_dt)
     if quant != "none":
-        cache["ks"] = jnp.zeros((n_blocks, bs, K), jnp.float32)
-        cache["vs"] = jnp.zeros((n_blocks, bs, K), jnp.float32)
+        cache["ks"] = jnp.zeros((n, n_blocks, bs, K), jnp.float32)
+        cache["vs"] = jnp.zeros((n, n_blocks, bs, K), jnp.float32)
     perm = rng.permutation(np.arange(1, n_blocks)).tolist()
     tables = np.full((b, m_blocks), -1, np.int32)
     for i in range(b):
@@ -176,7 +181,7 @@ def _paged_case(quant, seed=0, b=4, bs=16, m_blocks=8, n_blocks=64,
     hist = jnp.asarray([[p if p < st else -1 for p in range(H)]
                         for st in starts], jnp.int32)
     cache = A._paged_write_chunk(
-        cache, tables, jax.random.normal(ks[0], (b, H, K, hd), bf),
+        cache, LAYER, tables, jax.random.normal(ks[0], (b, H, K, hd), bf),
         jax.random.normal(ks[1], (b, H, K, hd), bf), hist)
     chunk_pos = jnp.asarray([[st + c if c < nv else -1 for c in range(C)]
                              for st, nv in zip(starts, n_valid)], jnp.int32)
@@ -239,7 +244,7 @@ def _kernel_checks():
 
         decode = {
             name: jax.jit(functools.partial(
-                A._paged_decode, blk=blk, settings=st))
+                A._paged_decode, blk=blk, settings=st, layer=LAYER))
             for name, st in (("kernel", kernel), ("jnp", jnp_path))}
         res = {n: f(case["q1"], cache, pos1=case["pos1"], k1=case["k1"],
                     v1=case["v1"], block_tables=tables)
@@ -253,7 +258,7 @@ def _kernel_checks():
 
         prefill = {
             name: jax.jit(functools.partial(
-                A._chunk_append, blk=blk, settings=st))
+                A._chunk_append, blk=blk, settings=st, layer=LAYER))
             for name, st in (("kernel", kernel), ("jnp", jnp_path))}
         res = {n: f(case["q_chunk"], case["k_chunk"], case["v_chunk"],
                     cache, positions=case["chunk_pos"],
@@ -265,22 +270,22 @@ def _kernel_checks():
         log(f"  chunk_prefill[{quant}]: max|kernel-jnp| over valid rows="
             f"{err:.3e} (tolerance {OUT_ATOL}) {'ok' if good else 'FAIL'}")
         ok &= bool(good)
-        # The pools the two paths wrote must agree outside scratch block
-        # 0: positions exactly; bf16 payload exactly (the kernel's merge
-        # is a full-precision one-hot copy); int8 codes within 1 (the
-        # chip's f32 division may round a .5 tie the other way) and f32
-        # scales within a relative 1e-6.
+        # The pools the two paths wrote must agree, every layer, outside
+        # scratch block 0: positions exactly; bf16 payload exactly (the
+        # kernel's merge is a full-precision one-hot copy); int8 codes
+        # within 1 (the chip's f32 division may round a .5 tie the other
+        # way) and f32 scales within a relative 1e-6.
         kc, jc = res["kernel"][1], res["jnp"][1]
-        pos_ok = np.array_equal(np.asarray(kc["pos"])[1:],
-                                np.asarray(jc["pos"])[1:])
-        pay = max(_max_err(np.asarray(kc[k])[1:], np.asarray(jc[k])[1:])
-                  for k in ("kb", "vb"))
+        pos_ok = np.array_equal(np.asarray(kc["pos"])[:, 1:],
+                                np.asarray(jc["pos"])[:, 1:])
+        pay = max(_max_err(np.asarray(kc[k])[:, 1:],
+                           np.asarray(jc[k])[:, 1:]) for k in ("kb", "vb"))
         pay_tol = 0.0 if quant == "none" else 1.0
         scale_err = 0.0
         if quant != "none":
             for k in ("ks", "vs"):
-                a = np.asarray(kc[k])[1:]
-                w = np.asarray(jc[k])[1:]
+                a = np.asarray(kc[k])[:, 1:]
+                w = np.asarray(jc[k])[:, 1:]
                 scale_err = max(scale_err, float(
                     np.max(np.abs(a - w) / np.maximum(np.abs(w), 1e-30))))
         good = pos_ok and pay <= pay_tol and scale_err <= 1e-6

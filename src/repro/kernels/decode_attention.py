@@ -105,8 +105,9 @@ def decode_attention_fwd(q, k_cache, v_cache, cache_pos, positions, *,
 # ---------------------------------------------------------------------------
 #
 # The pool stores KV in fixed-size position blocks shared across sequences
-# ([n_blocks, block, K, hd]); each sequence maps logical block j to a
-# physical block via its table row. The tables ride in as SCALAR PREFETCH
+# ([n_blocks, block, K, hd] per layer, stacked over layers); each sequence
+# maps logical block j to a physical block via its table row. The tables
+# and the layer index ride in as SCALAR PREFETCH
 # (pltpu.PrefetchScalarGridSpec) so the index_map itself can chase the
 # indirection — grid cell (b, j) DMAs exactly the physical block sequence b
 # needs, which is what makes decode traffic proportional to the blocks a
@@ -149,7 +150,8 @@ def _block_mask(cpos, pos, window: Optional[int], chunk: Optional[int]):
     return mask
 
 
-def _paged_kernel(tbl_ref, pos_ref, cpos_ref, q_ref, k_ref, v_ref, *refs,
+def _paged_kernel(tbl_ref, pos_ref, lyr_ref, cpos_ref, q_ref, k_ref, v_ref,
+                  *refs,
                   scale: float, window: Optional[int], chunk: Optional[int],
                   nl: int, n_kv: int, quant: str = "none",
                   mass: bool = False):
@@ -241,16 +243,21 @@ def paged_quant_of(k_pool) -> str:
 
 
 def paged_decode_attention_fwd(q, k_pool, v_pool, pool_pos, block_tables,
-                               positions, *,
+                               positions, *, layer,
                                window: Optional[int] = None,
                                chunk: Optional[int] = None,
                                k_scales=None, v_scales=None,
                                return_mass: bool = False,
                                interpret: bool = False):
-    """q [b,K,G,hd]; pools [n_blocks,block,K,hd] bf16 — or int8 / uint8
-    (packed int4 nibbles) with per-row f32 scales [n_blocks,block,K] in
-    `k_scales`/`v_scales`; pool_pos [n_blocks,block]; block_tables
-    [b,max_blocks] int32 (-1 = unassigned); positions [b].
+    """q [b,K,G,hd]; pools [layers,n_blocks,block,K,hd] bf16 — or int8 /
+    uint8 (packed int4 nibbles) with per-row f32 scales
+    [layers,n_blocks,block,K] in `k_scales`/`v_scales`; pool_pos
+    [layers,n_blocks,block]; block_tables [b,max_blocks] int32 (-1 =
+    unassigned); positions [b]; layer: an int32 scalar.
+
+    The pool is LAYER-STACKED and the kernel reads layer `layer` where it
+    lies: the index rides as scalar prefetch and the index maps address
+    (layer, physical block), so no layer's pool is ever sliced out.
 
     The grid's KV extent is the TABLE width, not the pool-wide max-context
     block count: callers that trim tables to the blocks actually allocated
@@ -270,7 +277,7 @@ def paged_decode_attention_fwd(q, k_pool, v_pool, pool_pos, block_tables,
     the standard log-sum-exp algebra."""
     b, K, G, hd = q.shape
     m_blocks = block_tables.shape[1]
-    n_blocks, bs = pool_pos.shape
+    n_layers, n_blocks, bs = pool_pos.shape
     quant = paged_quant_of(k_pool)
     if quant != "none" and (k_scales is None or v_scales is None):
         raise ValueError(f"{quant} pool needs k_scales/v_scales")
@@ -280,39 +287,42 @@ def paged_decode_attention_fwd(q, k_pool, v_pool, pool_pos, block_tables,
                                chunk=chunk, nl=m_blocks, n_kv=K, quant=quant,
                                mass=return_mass)
 
-    # index maps see the grid cell and both scalar-prefetch operands
-    # (block tables, decode positions); payload blocks chase the table
-    def physical(bi, li, tbl, pos):
-        return jnp.maximum(tbl[bi, li], 0)
+    # index maps see the grid cell and the three scalar-prefetch operands
+    # (block tables, decode positions, layer); payload blocks chase the
+    # table inside the layer's slab of the stacked pool
+    def pool_block(ndim):
+        return lambda bi, li, tbl, pos, lyr: (
+            (lyr[0], jnp.maximum(tbl[bi, li], 0)) + (0,) * (ndim - 2))
 
-    def per_lane(bi, li, tbl, pos):
+    def per_lane(bi, li, tbl, pos, lyr):
         return bi, 0, 0, 0
 
-    # positions ride as [n_blocks, 1, bs] so a block's row is a full-dim
-    # (1, bs) tile instead of a sub-tile (1, bs) slice of [n_blocks, bs]
+    # positions ride as [layers, n_blocks, 1, bs] so a block's row is a
+    # full-dim (1, bs) tile instead of a sub-tile slice of [n_blocks, bs]
+    sq = pl.squeezed
     in_specs = [
-        pl.BlockSpec((1, 1, bs), lambda *g: (physical(*g), 0, 0)),
+        pl.BlockSpec((sq, 1, 1, bs), pool_block(4)),
         pl.BlockSpec((1, K, G, hd), per_lane),
-        pl.BlockSpec((1, bs, K, hd_s), lambda *g: (physical(*g), 0, 0, 0)),
-        pl.BlockSpec((1, bs, K, hd_s), lambda *g: (physical(*g), 0, 0, 0)),
+        pl.BlockSpec((sq, 1, bs, K, hd_s), pool_block(5)),
+        pl.BlockSpec((sq, 1, bs, K, hd_s), pool_block(5)),
     ]
     args = [block_tables.astype(jnp.int32), positions.astype(jnp.int32),
-            pool_pos.reshape(n_blocks, 1, bs), q, k_pool, v_pool]
+            jnp.asarray(layer, jnp.int32).reshape(1),
+            pool_pos.reshape(n_layers, n_blocks, 1, bs), q, k_pool, v_pool]
     if quant != "none":
         # scale stripes chase the same block table as their payload
-        in_specs += [pl.BlockSpec((1, bs, K),
-                                  lambda *g: (physical(*g), 0, 0))] * 2
+        in_specs += [pl.BlockSpec((sq, 1, bs, K), pool_block(4))] * 2
         args += [k_scales, v_scales]
     out_specs = [pl.BlockSpec((1, K, G, hd), per_lane)]
     out_shape = [jax.ShapeDtypeStruct((b, K, G, hd), q.dtype)]
     if return_mass:
         out_specs += [pl.BlockSpec(
             (1, 1, K, G, 1),
-            lambda bi, li, tbl, pos: (bi, li, 0, 0, 0))] * 2
+            lambda bi, li, tbl, pos, lyr: (bi, li, 0, 0, 0))] * 2
         out_shape += [jax.ShapeDtypeStruct((b, m_blocks, K, G, 1),
                                            jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, m_blocks),
         in_specs=in_specs,
         out_specs=out_specs if return_mass else out_specs[0],

@@ -187,47 +187,51 @@ def decode_attention(q, k_cache, v_cache, cache_pos, positions, *,
 
 
 def paged_decode_attention(q, k_pool, v_pool, pool_pos, block_tables,
-                           positions, *, window: Optional[int] = None,
+                           positions, *, layer,
+                           window: Optional[int] = None,
                            chunk: Optional[int] = None,
                            backend: str = "pallas",
                            k_scales=None, v_scales=None,
                            return_mass: bool = False):
-    """Decode through a paged KV pool: q [b,K,G,hd]; pools
-    [n_blocks,block,K,hd] (bf16, int8, or uint8-packed int4 with per-row
-    f32 `k_scales`/`v_scales` [n_blocks,block,K]); pool_pos
-    [n_blocks,block]; block_tables [b,max_blocks] (-1 = unassigned) ->
-    [b,K,G,hd], or (out, mass [b,max_blocks]) with `return_mass`.
-    Quantized pools are DMA'd and dequantized inside the kernel — no fp
-    pool copy. There is no jnp twin here (the kernel IS the gather; the
-    jnp path lives in models.attention): `backend` is "pallas" —
-    compiled on a TPU, interpreted elsewhere — or "interpret" off-TPU."""
+    """Decode through layer `layer` of a layer-stacked paged KV pool:
+    q [b,K,G,hd]; pools [layers,n_blocks,block,K,hd] (bf16, int8, or
+    uint8-packed int4 with per-row f32 `k_scales`/`v_scales`
+    [layers,n_blocks,block,K]); pool_pos [layers,n_blocks,block];
+    block_tables [b,max_blocks] (-1 = unassigned) -> [b,K,G,hd], or (out,
+    mass [b,max_blocks]) with `return_mass`. Quantized pools are DMA'd and
+    dequantized inside the kernel — no fp pool copy. There is no jnp twin
+    here (the kernel IS the gather; the jnp path lives in
+    models.attention): `backend` is "pallas" — compiled on a TPU,
+    interpreted elsewhere — or "interpret" off-TPU."""
     return paged_decode_attention_fwd(
-        q, k_pool, v_pool, pool_pos, block_tables, positions,
+        q, k_pool, v_pool, pool_pos, block_tables, positions, layer=layer,
         window=window, chunk=chunk, k_scales=k_scales, v_scales=v_scales,
         return_mass=return_mass,
         interpret=_interpret(backend, "decode"))
 
 
 def paged_prefill_attention(q, k_new, v_new, k_pool, v_pool, pool_pos,
-                            block_tables, positions, *,
+                            block_tables, positions, *, layer,
                             window: Optional[int] = None,
                             chunk: Optional[int] = None,
                             backend: str = "pallas",
                             k_scales=None, v_scales=None):
-    """Fused chunked prefill through a paged KV pool: write the chunk's
-    K/V into the pool via the block tables (quantize-on-write in-kernel
-    for int8/int4 pools — no fp intermediate in HBM) and flash-attend the
-    chunk's queries over history + chunk in O(chunk x block) tiles.
+    """Fused chunked prefill through layer `layer` of a layer-stacked
+    paged KV pool: write the chunk's K/V into the pool via the block
+    tables (quantize-on-write in-kernel for int8/int4 pools — no fp
+    intermediate in HBM) and flash-attend the chunk's queries over
+    history + chunk in O(chunk x block) tiles.
 
-    q [b,C,K,G,hd]; k_new/v_new [b,C,K,hd]; pools [n_blocks,block,K,hd]
-    (bf16, int8, or uint8-packed int4 with f32 `k_scales`/`v_scales`);
-    pool_pos [n_blocks,block]; block_tables [b,max_blocks]; positions
-    [b,C] (-1 = padding). Returns (o, pool_pos', k_pool', v_pool'[, ks',
-    vs']). Like paged decode there is no jnp twin: the kernel IS the
-    scatter + gather, and `backend` is "pallas" or "interpret"."""
+    q [b,C,K,G,hd]; k_new/v_new [b,C,K,hd]; pools
+    [layers,n_blocks,block,K,hd] (bf16, int8, or uint8-packed int4 with f32
+    `k_scales`/`v_scales`); pool_pos [layers,n_blocks,block]; block_tables
+    [b,max_blocks]; positions [b,C] (-1 = padding). Returns (o, pool_pos',
+    k_pool', v_pool'[, ks', vs']), the pool leaves updated in place. Like
+    paged decode there is no jnp twin: the kernel IS the scatter + gather,
+    and `backend` is "pallas" or "interpret"."""
     return paged_prefill_attention_fwd(
         q, k_new, v_new, k_pool, v_pool, pool_pos, block_tables, positions,
-        window=window, chunk_mask=chunk, k_scales=k_scales,
+        layer=layer, window=window, chunk_mask=chunk, k_scales=k_scales,
         v_scales=v_scales, interpret=_interpret(backend, "chunk_prefill"))
 
 

@@ -98,8 +98,8 @@ def _gather_rows(sel, x):
     return _dot(sel, x, 0, precision=jax.lax.Precision.HIGHEST)
 
 
-def _prefill_kernel(tbl_ref, qrow_ref, qcol_ref, qg_ref, q_ref, kn_ref,
-                    vn_ref, pp_ref, kp_ref, vp_ref, *refs,
+def _prefill_kernel(tbl_ref, lyr_ref, qrow_ref, qcol_ref, qg_ref, q_ref,
+                    kn_ref, vn_ref, pp_ref, kp_ref, vp_ref, *refs,
                     scale: float, window: Optional[int],
                     chunk_mask: Optional[int], nl: int, bs: int, n_kv: int,
                     quant: str):
@@ -232,27 +232,32 @@ def _prefill_kernel(tbl_ref, qrow_ref, qcol_ref, qg_ref, q_ref, kn_ref,
 
 
 def paged_prefill_attention_fwd(q, k_new, v_new, k_pool, v_pool, pool_pos,
-                                block_tables, positions, *,
+                                block_tables, positions, *, layer,
                                 window: Optional[int] = None,
                                 chunk_mask: Optional[int] = None,
                                 k_scales=None, v_scales=None,
                                 interpret: bool = False):
     """Fused chunk write + causal flash attend through a paged KV pool.
 
-    q [b,C,K,G,hd]; k_new/v_new [b,C,K,hd] fp; pools [n_blocks,block,K,hd]
-    bf16 — or int8 / uint8 (packed int4) with per-row f32 scales
-    [n_blocks,block,K] in `k_scales`/`v_scales`; pool_pos [n_blocks,block];
-    block_tables [b,max_blocks] int32 (-1 = unassigned); positions [b,C]
-    int32 (-1 = padding rows of a short final chunk).
+    q [b,C,K,G,hd]; k_new/v_new [b,C,K,hd] fp; pools
+    [layers,n_blocks,block,K,hd] bf16 — or int8 / uint8 (packed int4) with
+    per-row f32 scales [layers,n_blocks,block,K] in `k_scales`/`v_scales`;
+    pool_pos [layers,n_blocks,block]; block_tables [b,max_blocks] int32
+    (-1 = unassigned); positions [b,C] int32 (-1 = padding rows of a short
+    final chunk); layer: an int32 scalar.
 
     Returns (o [b,C,K,G,hd], pool_pos', k_pool', v_pool'[, k_scales',
     v_scales']) — the pool leaves are updated IN PLACE via
     input_output_aliases; physical blocks no table entry points at keep
     their contents. As with paged decode, the grid's KV extent is the
-    table width, so trimmed tables shrink prefill work too."""
+    table width, so trimmed tables shrink prefill work too.
+
+    The pool is LAYER-STACKED, as paged decode takes it: the kernel reads
+    and writes layer `layer`'s blocks where they lie, and every other
+    layer's blocks keep their contents like unvisited ones."""
     b, C, K, G, hd = q.shape
     m_blocks = block_tables.shape[1]
-    n_blocks, bs = pool_pos.shape
+    n_layers, n_blocks, bs = pool_pos.shape
     quant = paged_quant_of(k_pool)
     if quant != "none" and (k_scales is None or v_scales is None):
         raise ValueError(f"{quant} pool needs k_scales/v_scales")
@@ -262,24 +267,24 @@ def paged_prefill_attention_fwd(q, k_new, v_new, k_pool, v_pool, pool_pos,
                                chunk_mask=chunk_mask, nl=m_blocks, bs=bs,
                                n_kv=K, quant=quant)
 
-    def physical(bi, li, tbl):
-        return jnp.maximum(tbl[bi, li], 0)
-
     def lane(ndim):
-        return lambda bi, li, tbl: (bi,) + (0,) * (ndim - 1)
+        return lambda bi, li, tbl, lyr: (bi,) + (0,) * (ndim - 1)
 
     def pool(ndim):
-        return lambda bi, li, tbl: (physical(bi, li, tbl),) + (0,) * (ndim - 1)
+        return lambda bi, li, tbl, lyr: (
+            (lyr[0], jnp.maximum(tbl[bi, li], 0)) + (0,) * (ndim - 2))
 
     positions = positions.astype(jnp.int32)
     # per kv head, the chunk's queries as rows c*G + g, and the positions
     # as a row, a column, and a column repeated per query row
     qh = jnp.swapaxes(q, 1, 2).reshape(b, K, C * G, hd)
     args = [block_tables.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1),
             positions.reshape(b, 1, C), positions.reshape(b, C, 1),
             jnp.repeat(positions, G, axis=1).reshape(b, C * G, 1),
-            qh, k_new, v_new, pool_pos.reshape(n_blocks, 1, bs),
+            qh, k_new, v_new, pool_pos.reshape(n_layers, n_blocks, 1, bs),
             k_pool, v_pool]
+    sq = pl.squeezed
     in_specs = [
         pl.BlockSpec((1, 1, C), lane(3)),
         pl.BlockSpec((1, C, 1), lane(3)),
@@ -287,34 +292,35 @@ def paged_prefill_attention_fwd(q, k_new, v_new, k_pool, v_pool, pool_pos,
         pl.BlockSpec((1, K, C * G, hd), lane(4)),
         pl.BlockSpec((1, C, K, hd), lane(4)),
         pl.BlockSpec((1, C, K, hd), lane(4)),
-        pl.BlockSpec((1, 1, bs), pool(3)),
-        pl.BlockSpec((1, bs, K, hd_s), pool(4)),
-        pl.BlockSpec((1, bs, K, hd_s), pool(4)),
+        pl.BlockSpec((sq, 1, 1, bs), pool(4)),
+        pl.BlockSpec((sq, 1, bs, K, hd_s), pool(5)),
+        pl.BlockSpec((sq, 1, bs, K, hd_s), pool(5)),
     ]
     out_specs = [
         pl.BlockSpec((1, K, C * G, hd), lane(4)),
-        pl.BlockSpec((1, 1, bs), pool(3)),
-        pl.BlockSpec((1, bs, K, hd_s), pool(4)),
-        pl.BlockSpec((1, bs, K, hd_s), pool(4)),
+        pl.BlockSpec((sq, 1, 1, bs), pool(4)),
+        pl.BlockSpec((sq, 1, bs, K, hd_s), pool(5)),
+        pl.BlockSpec((sq, 1, bs, K, hd_s), pool(5)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b, K, C * G, hd), q.dtype),
-        jax.ShapeDtypeStruct((n_blocks, 1, bs), pool_pos.dtype),
+        jax.ShapeDtypeStruct((n_layers, n_blocks, 1, bs), pool_pos.dtype),
         jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
         jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
     ]
-    # alias indices COUNT the scalar-prefetch operand: block_tables is
-    # input 0, so pool_pos / k_pool / v_pool sit at 7 / 8 / 9
-    aliases = {7: 1, 8: 2, 9: 3}
+    # alias indices COUNT the scalar-prefetch operands: block_tables and
+    # layer are inputs 0 and 1, so pool_pos / k_pool / v_pool sit at
+    # 8 / 9 / 10
+    aliases = {8: 1, 9: 2, 10: 3}
     if quant != "none":
-        in_specs += [pl.BlockSpec((1, bs, K), pool(3))] * 2
-        out_specs += [pl.BlockSpec((1, bs, K), pool(3))] * 2
+        in_specs += [pl.BlockSpec((sq, 1, bs, K), pool(4))] * 2
+        out_specs += [pl.BlockSpec((sq, 1, bs, K), pool(4))] * 2
         args += [k_scales, v_scales]
         out_shape += [jax.ShapeDtypeStruct(k_scales.shape, k_scales.dtype),
                       jax.ShapeDtypeStruct(v_scales.shape, v_scales.dtype)]
-        aliases.update({10: 4, 11: 5})
+        aliases.update({11: 4, 12: 5})
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, m_blocks),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -334,4 +340,4 @@ def paged_prefill_attention_fwd(q, k_new, v_new, k_pool, v_pool, pool_pos,
         interpret=interpret,
     )(*args)
     o = jnp.swapaxes(out[0].reshape(b, K, C, G, hd), 1, 2)
-    return (o, out[1].reshape(n_blocks, bs)) + tuple(out[2:])
+    return (o, out[1].reshape(n_layers, n_blocks, bs)) + tuple(out[2:])

@@ -353,6 +353,11 @@ def _decode_attend(q, cache, blk: BlockSpec, positions,
 # blocks shared by every sequence, and each sequence's logical layout is a
 # block table [max_blocks] mapping logical block j (positions j*block ..
 # (j+1)*block - 1) to a physical block id (-1 = not yet allocated).
+# Attention takes the pool LAYER-STACKED (every leaf [layers, n_blocks,
+# ...], as the serving pool holds it) with the layer's index, and reads
+# and writes that layer's blocks where they lie: the layer scan carries
+# the whole stack (models.model.apply), so no step slices a layer's pool
+# out or stacks it back.
 # Physical block 0 is the SCRATCH block: inactive decode lanes (table all
 # -1) read and write it harmlessly, so one batched decode serves any pool
 # occupancy with a single compile. Only full-context layers page; short
@@ -412,13 +417,14 @@ def is_paged_cache(cache) -> bool:
     return isinstance(cache, dict) and "kb" in cache
 
 
-def _paged_write(cache, block_tables, k1, v1, pos1):
-    """Write one token per lane (k1/v1 [b,K,hd], pos1 [b]) into the pool at
-    (table[pos // block], pos % block). Lanes with no block mapped (table
-    entry -1) and INERT lanes (pos1 < 0 — a padding row the engine carries
-    at full decode width while the lane is empty or mid-chunk-prefill) land
-    in the scratch block with pos -1, so they can never clobber live KV."""
-    n_blocks, bsz = cache["pos"].shape
+def _paged_write(cache, layer, block_tables, k1, v1, pos1):
+    """Write one token per lane (k1/v1 [b,K,hd], pos1 [b]) into layer
+    `layer` of the stacked pool at (table[pos // block], pos % block).
+    Lanes with no block mapped (table entry -1) and INERT lanes (pos1 < 0
+    — a padding row the engine carries at full decode width while the
+    lane is empty or mid-chunk-prefill) land in the scratch block with
+    pos -1, so they can never clobber live KV."""
+    bsz = cache["pos"].shape[-1]
     m_blocks = block_tables.shape[1]
     live = pos1 >= 0
     safe_pos = jnp.where(live, pos1, 0)
@@ -430,30 +436,35 @@ def _paged_write(cache, block_tables, k1, v1, pos1):
     kq, ks = quantize_kv(k1, kind)
     vq, vs = quantize_kv(v1, kind)
     out = {
-        "kb": cache["kb"].at[phys, off].set(kq.astype(cache["kb"].dtype)),
-        "vb": cache["vb"].at[phys, off].set(vq.astype(cache["vb"].dtype)),
-        "pos": cache["pos"].at[phys, off].set(jnp.where(live, pos1, -1)),
+        "kb": cache["kb"].at[layer, phys, off].set(
+            kq.astype(cache["kb"].dtype)),
+        "vb": cache["vb"].at[layer, phys, off].set(
+            vq.astype(cache["vb"].dtype)),
+        "pos": cache["pos"].at[layer, phys, off].set(
+            jnp.where(live, pos1, -1)),
     }
     if kind != "none":
-        out["ks"] = cache["ks"].at[phys, off].set(ks)
-        out["vs"] = cache["vs"].at[phys, off].set(vs)
+        out["ks"] = cache["ks"].at[layer, phys, off].set(ks)
+        out["vs"] = cache["vs"].at[layer, phys, off].set(vs)
     return out
 
 
-def _paged_gather(cache, block_tables):
-    """Gather each lane's blocks into a contiguous virtual ring
-    ([b, max_blocks*block, ...]): unassigned table entries read the scratch
-    block with their positions masked to -1, so downstream masking treats
-    them as empty slots."""
+def _paged_gather(cache, layer, block_tables):
+    """Gather each lane's blocks of layer `layer` into a contiguous
+    virtual ring ([b, max_blocks*block, ...]): unassigned table entries
+    read the scratch block with their positions masked to -1, so
+    downstream masking treats them as empty slots."""
     b, m_blocks = block_tables.shape
-    bsz = cache["pos"].shape[1]
+    bsz = cache["pos"].shape[-1]
     safe = jnp.where(block_tables >= 0, block_tables, 0)
-    pos = jnp.where(block_tables[..., None] >= 0, cache["pos"][safe], -1)
+    pos = jnp.where(block_tables[..., None] >= 0,
+                    cache["pos"][layer, safe], -1)
     kind = paged_quant_kind(cache)
-    k, v = cache["kb"][safe], cache["vb"][safe]  # [b, mB, bs, K, hd']
+    k = cache["kb"][layer, safe]                 # [b, mB, bs, K, hd']
+    v = cache["vb"][layer, safe]
     if kind != "none":
-        k = dequantize_kv(k, cache["ks"][safe], kind)
-        v = dequantize_kv(v, cache["vs"][safe], kind)
+        k = dequantize_kv(k, cache["ks"][layer, safe], kind)
+        v = dequantize_kv(v, cache["vs"][layer, safe], kind)
     return {
         "k": k.reshape(b, m_blocks * bsz, *k.shape[3:]),
         "v": v.reshape(b, m_blocks * bsz, *v.shape[3:]),
@@ -461,13 +472,14 @@ def _paged_gather(cache, block_tables):
     }
 
 
-def _paged_write_chunk(cache, block_tables, k, v, positions):
+def _paged_write_chunk(cache, layer, block_tables, k, v, positions):
     """Write a prompt chunk per lane (k/v [b, C, K, hd], positions [b, C],
-    -1 = padding) into the pool through the block tables. Padding entries
+    -1 = padding) into layer `layer` of the stacked pool through the block
+    tables. Padding entries
     and entries whose logical block is unmapped land in the scratch block
     with pos -1, so nothing real can be clobbered and nothing stale can
     pass the mask."""
-    n_blocks, bsz = cache["pos"].shape
+    bsz = cache["pos"].shape[-1]
     m_blocks = block_tables.shape[1]
     valid = positions >= 0
     safe_pos = jnp.where(valid, positions, 0)
@@ -479,19 +491,21 @@ def _paged_write_chunk(cache, block_tables, k, v, positions):
     kq, ks = quantize_kv(k, kind)
     vq, vs = quantize_kv(v, kind)
     out = {
-        "kb": cache["kb"].at[phys, off].set(kq.astype(cache["kb"].dtype)),
-        "vb": cache["vb"].at[phys, off].set(vq.astype(cache["vb"].dtype)),
-        "pos": cache["pos"].at[phys, off].set(
+        "kb": cache["kb"].at[layer, phys, off].set(
+            kq.astype(cache["kb"].dtype)),
+        "vb": cache["vb"].at[layer, phys, off].set(
+            vq.astype(cache["vb"].dtype)),
+        "pos": cache["pos"].at[layer, phys, off].set(
             jnp.where(valid, positions, -1)),
     }
     if kind != "none":
-        out["ks"] = cache["ks"].at[phys, off].set(ks)
-        out["vs"] = cache["vs"].at[phys, off].set(vs)
+        out["ks"] = cache["ks"].at[layer, phys, off].set(ks)
+        out["vs"] = cache["vs"].at[layer, phys, off].set(vs)
     return out
 
 
 def _chunk_append(q, k, v, cache, blk: BlockSpec, positions, block_tables,
-                  settings: AttnSettings = AttnSettings()):
+                  settings: AttnSettings = AttnSettings(), layer=None):
     """Chunked prefill: append a prompt chunk to an EXISTING cache and
     attend over history + chunk — exactly the chunk's slice of a full
     prefill, so interleaving chunks with decode ticks changes scheduling
@@ -504,36 +518,39 @@ def _chunk_append(q, k, v, cache, blk: BlockSpec, positions, block_tables,
     kernel exists to avoid); per-lane rings attend over concat(ring,
     chunk) and then keep only the last cache_len positions (slot = pos % L
     stays collision-free because the kept span is at most L consecutive
-    positions)."""
+    positions). A paged cache is the layer-stacked pool and `layer` the
+    index of this layer in it."""
     b, C = positions.shape
     valid = positions >= 0
     if is_paged_cache(cache):
-        assert block_tables is not None, \
-            "paged cache needs block_tables for chunked prefill"
+        assert block_tables is not None and layer is not None, \
+            "paged cache needs block_tables and layer for chunked prefill"
         if settings.backend == "pallas":
             quant = paged_quant_kind(cache)
             scales = ((cache["ks"], cache["vs"]) if quant != "none" else ())
 
-            def prefill(q, k, v, kb, vb, pos, tables, qpos, *scales):
+            def prefill(q, k, v, kb, vb, pos, tables, qpos, lyr, *scales):
                 ks, vs = scales or (None, None)
                 return kops.paged_prefill_attention(
-                    q, k, v, kb, vb, pos, tables, qpos, window=blk.window,
-                    chunk=blk.chunk, backend="pallas", k_scales=ks,
-                    v_scales=vs)
+                    q, k, v, kb, vb, pos, tables, qpos, layer=lyr,
+                    window=blk.window, chunk=blk.chunk, backend="pallas",
+                    k_scales=ks, v_scales=vs)
 
             out = _over_kv_heads(
                 prefill, (q, k, v, cache["kb"], cache["vb"], cache["pos"],
-                          block_tables, positions) + scales,
-                (2, 2, 2, 2, 2, None, None, None) + (2,) * len(scales),
-                (2, None, 2, 2) + (2,) * len(scales))
+                          block_tables, positions,
+                          jnp.asarray(layer, jnp.int32)) + scales,
+                (2, 2, 2, 3, 3, None, None, None, None) + (3,) * len(scales),
+                (2, None, 3, 3) + (3,) * len(scales))
             o, ppos, kb, vb = out[:4]
             new_cache = {"kb": kb, "vb": vb, "pos": ppos}
             if quant != "none":
                 new_cache["ks"], new_cache["vs"] = out[4], out[5]
             return o, new_cache
         kops.note_trace("chunk_prefill", "jnp")
-        new_cache = _paged_write_chunk(cache, block_tables, k, v, positions)
-        virt = _paged_gather(new_cache, block_tables)
+        new_cache = _paged_write_chunk(cache, layer, block_tables, k, v,
+                                       positions)
+        virt = _paged_gather(new_cache, layer, block_tables)
         o = _sdpa(q, virt["k"], virt["v"],
                   _mask(positions, virt["pos"], blk))
         return o, new_cache
@@ -560,39 +577,41 @@ def _chunk_append(q, k, v, cache, blk: BlockSpec, positions, block_tables,
 
 
 def _paged_decode(q, cache, blk: BlockSpec, pos1, k1, v1, block_tables,
-                  settings: AttnSettings):
-    """One decode step against the paged pool: scatter the new K/V entry,
-    then attend through the block table — via the Pallas paged kernel
+                  settings: AttnSettings, layer):
+    """One decode step against layer `layer` of the stacked paged pool:
+    scatter the new K/V entry in place, then attend through the block
+    table — via the Pallas paged kernel
     (interpret-mode off-TPU; quantized pools dequant IN-kernel on the
     block-table DMA path) or the jnp gather fallback. Returns
     (o, new_cache, mass or None): `mass` [b, max_blocks] is each logical
     block's softmax share, emitted when settings.track_mass."""
-    new_cache = _paged_write(cache, block_tables, k1, v1, pos1)
+    new_cache = _paged_write(cache, layer, block_tables, k1, v1, pos1)
     b, m_blocks = block_tables.shape
-    bsz = cache["pos"].shape[1]
+    bsz = cache["pos"].shape[-1]
     if settings.backend == "pallas":
         quant = paged_quant_kind(new_cache)
         scales = ((new_cache["ks"], new_cache["vs"]) if quant != "none"
                   else ())
 
-        def decode(q1, kb, vb, pos, tables, pos1, *scales):
+        def decode(q1, kb, vb, pos, tables, pos1, lyr, *scales):
             ks, vs = scales or (None, None)
             return kops.paged_decode_attention(
-                q1, kb, vb, pos, tables, pos1, window=blk.window,
+                q1, kb, vb, pos, tables, pos1, layer=lyr, window=blk.window,
                 chunk=blk.chunk, backend="pallas", k_scales=ks, v_scales=vs,
                 return_mass=settings.track_mass)
 
         out = _over_kv_heads(
             decode, (q[:, 0], new_cache["kb"], new_cache["vb"],
-                     new_cache["pos"], block_tables, pos1) + scales,
-            (1, 2, 2, None, None, None) + (2,) * len(scales),
+                     new_cache["pos"], block_tables, pos1,
+                     jnp.asarray(layer, jnp.int32)) + scales,
+            (1, 3, 3, None, None, None, None) + (3,) * len(scales),
             (1, "mean") if settings.track_mass else 1)
         if settings.track_mass:
             o, mass = out
             return o[:, None], new_cache, mass
         return out[:, None], new_cache, None
     kops.note_trace("decode", "jnp")
-    virt = _paged_gather(new_cache, block_tables)
+    virt = _paged_gather(new_cache, layer, block_tables)
     if settings.track_mass:
         o, p = _decode_attend(q, virt, blk, pos1, return_probs=True)
         # p [b, K, G, 1, mB*bs]: average heads, fold positions into blocks
@@ -607,10 +626,13 @@ def _paged_decode(q, cache, blk: BlockSpec, pos1, k1, v1, block_tables,
 
 def attn_apply(params, cfg: ModelConfig, blk: BlockSpec, x, positions,
                cache=None, decode: bool = False, context: int = 0,
-               settings: AttnSettings = AttnSettings(), block_tables=None):
+               settings: AttnSettings = AttnSettings(), block_tables=None,
+               layer=None):
     """x [b, s, d]; positions [b, s] (s=1 for decode). `block_tables`
-    [b, max_blocks] routes decode through a paged pool cache (see the
-    paged-KV section above) when the layer's cache is paged.
+    [b, max_blocks] routes decode and chunked prefill through a paged pool
+    cache (see the paged-KV section above) when the layer's cache is paged;
+    such a cache is the layer-stacked pool, and `layer` this layer's index
+    in it.
 
     Returns (y [b, s, d], new_cache or None, aux dict). `aux` carries
     "attn_mass" [b, max_blocks] on paged decode when settings.track_mass
@@ -654,11 +676,11 @@ def attn_apply(params, cfg: ModelConfig, blk: BlockSpec, x, positions,
         assert cache is not None and s == 1
         pos1 = positions.reshape(b)              # accept [b] or [b, 1]
         if is_paged_cache(cache):
-            assert block_tables is not None, \
-                "paged cache needs block_tables at decode"
+            assert block_tables is not None and layer is not None, \
+                "paged cache needs block_tables and layer at decode"
             o, new_cache, mass = _paged_decode(q, cache, blk, pos1, k[:, 0],
                                                v[:, 0], block_tables,
-                                               settings)
+                                               settings, layer)
             if mass is not None:
                 aux["attn_mass"] = mass
         else:
@@ -677,7 +699,7 @@ def attn_apply(params, cfg: ModelConfig, blk: BlockSpec, x, positions,
         # chunked prefill: a real cache on the sequence path means "append
         # this chunk to what the earlier chunks already wrote"
         o, new_cache = _chunk_append(q, k, v, cache, blk, positions,
-                                     block_tables, settings)
+                                     block_tables, settings, layer)
     else:
         kpos = positions
         if use_repeat:

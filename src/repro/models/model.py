@@ -121,8 +121,9 @@ def block_cache_init(cfg: ModelConfig, blk: BlockSpec, batch: int,
 def block_apply(params, cfg: ModelConfig, blk: BlockSpec, x, positions,
                 cache=None, decode: bool = False, context: int = 0,
                 settings: ModelSettings = ModelSettings(),
-                block_tables=None):
-    """Returns (x', new_cache, aux)."""
+                block_tables=None, layer=None):
+    """Returns (x', new_cache, aux). A paged attention cache is the
+    layer-stacked pool, and `layer` this block's index in it."""
     aux = _zero_aux()
     building = settings.build_cache and not decode and cache is None
     if blk.mixer == ATTN:
@@ -131,7 +132,7 @@ def block_apply(params, cfg: ModelConfig, blk: BlockSpec, x, positions,
         delta, new_cache, attn_aux = attention.attn_apply(
             params["mixer"], cfg, blk, x, positions, cache=cache_arg,
             decode=decode, context=context, settings=settings.attn,
-            block_tables=block_tables)
+            block_tables=block_tables, layer=layer)
         aux = {**aux, **attn_aux}
     else:
         if building:  # prefill: recurrent blocks start from zero state
@@ -342,8 +343,23 @@ def apply(params, cfg: ModelConfig, tokens, *, positions=None,
             (b, block_tables.shape[1]), jnp.float32)
     want_cache = decode or settings.build_cache
     have_cache = cache is not None
+    # A paged pool leaf (attention.is_paged_cache) stays ONE layer-stacked
+    # buffer that every layer updates in place at its own index: the layer
+    # scan carries it whole. As scan xs/ys it would be sliced out for each
+    # layer and stacked back, copying the whole pool every step. Per-lane
+    # caches (rings, recurrent states) ride xs/ys.
+    caches_in = cache["units"] if have_cache else [None] * len(cfg.unit)
+    paged = [attention.is_paged_cache(c) for c in caches_in]
 
-    def unit_body(x, unit_params, unit_caches):
+    def split(caches):
+        """(paged pools, per-lane caches), None where the other holds it."""
+        return ([c if pg else None for c, pg in zip(caches, paged)],
+                [None if pg else c for c, pg in zip(caches, paged)])
+
+    def join(pools, lanes):
+        return [p if pg else c for p, c, pg in zip(pools, lanes, paged)]
+
+    def unit_body(x, unit_params, unit_caches, layer):
         new_caches = []
         aux_sum = dict(zero_aux)
         for i, blk in enumerate(cfg.unit):
@@ -351,7 +367,7 @@ def apply(params, cfg: ModelConfig, tokens, *, positions=None,
             x, nc, aux = block_apply(unit_params[i], cfg, blk, x, pos,
                                      cache=c, decode=decode, context=ctx,
                                      settings=settings,
-                                     block_tables=block_tables)
+                                     block_tables=block_tables, layer=layer)
             new_caches.append(nc)
             aux_sum = {k: aux_sum[k] + aux.get(k, 0) for k in aux_sum}
         return x, new_caches, aux_sum
@@ -369,37 +385,43 @@ def apply(params, cfg: ModelConfig, tokens, *, positions=None,
         new_unit_caches = None
     elif cfg.unit and settings.scan_layers:
         def scan_body(carry, xs):
-            x, aux_acc = carry
-            unit_params = xs[:len(cfg.unit)]
-            unit_caches = (list(xs[len(cfg.unit):]) if have_cache else None)
-            x, new_caches, aux = unit_body(x, list(unit_params), unit_caches)
+            x, aux_acc, pools = carry
+            layer, unit_params, lanes = xs
+            unit_caches = join(pools, lanes) if have_cache else None
+            x, new_caches, aux = unit_body(x, list(unit_params), unit_caches,
+                                           layer)
             aux_acc = {k: aux_acc[k] + aux.get(k, 0) for k in aux_acc}
-            ys = tuple(new_caches) if want_cache else ()
-            return (x, aux_acc), ys
+            pools, lanes = split(new_caches)
+            ys = tuple(lanes) if want_cache else ()
+            return (x, aux_acc, tuple(pools)), ys
 
-        xs = tuple(params["units"])
-        if have_cache:
-            xs = xs + tuple(cache["units"])
-        (x, aux_acc), ys = jax.lax.scan(scan_body, (x, dict(zero_aux)), xs)
-        new_unit_caches = list(ys) if want_cache else None
+        pools, lanes = split(caches_in)
+        xs = (jnp.arange(cfg.repeats, dtype=jnp.int32),
+              tuple(params["units"]), tuple(lanes))
+        (x, aux_acc, pools), ys = jax.lax.scan(
+            scan_body, (x, dict(zero_aux), tuple(pools)), xs)
+        new_unit_caches = join(pools, ys) if want_cache else None
     elif cfg.unit:
         # Unrolled path: python loop over repeats (exact per-layer HLO cost).
         aux_acc = dict(zero_aux)
+        pools, lanes = split(caches_in)
         collected = []
         for r in range(cfg.repeats):
             unit_params = [jax.tree.map(lambda a: a[r], t)
                            for t in params["units"]]
-            unit_caches = ([jax.tree.map(lambda a: a[r], t)
-                            for t in cache["units"]] if have_cache else None)
-            x, new_caches, aux = unit_body(x, unit_params, unit_caches)
+            unit_caches = (join(pools, [jax.tree.map(lambda a: a[r], c)
+                                        for c in lanes])
+                           if have_cache else None)
+            x, new_caches, aux = unit_body(x, unit_params, unit_caches,
+                                           jnp.int32(r))
             aux_acc = {k: aux_acc[k] + aux.get(k, 0) for k in aux_acc}
-            if want_cache:
-                collected.append(new_caches)
+            pools, lanes_r = split(new_caches)
+            collected.append(lanes_r)
         if want_cache and collected:
-            new_unit_caches = [
+            new_unit_caches = join(pools, [
                 jax.tree.map(lambda *leaves: jnp.stack(leaves),
                              *[collected[r][i] for r in range(cfg.repeats)])
-                for i in range(len(cfg.unit))]
+                for i in range(len(cfg.unit))])
         else:
             new_unit_caches = None
     else:
@@ -409,10 +431,16 @@ def apply(params, cfg: ModelConfig, tokens, *, positions=None,
     new_tail_caches = []
     for i, blk in enumerate(cfg.tail):
         c = cache["tail"][i] if have_cache else None
+        tail_paged = attention.is_paged_cache(c)
+        if tail_paged:             # a one-layer stack: a free reshape
+            c = jax.tree.map(lambda a: a[None], c)
         x, nc, aux = block_apply(params["tail"][i], cfg, blk, x, pos,
                                  cache=c, decode=decode, context=ctx,
                                  settings=settings,
-                                 block_tables=block_tables)
+                                 block_tables=block_tables,
+                                 layer=jnp.int32(0))
+        if tail_paged:
+            nc = jax.tree.map(lambda a: a[0], nc)
         new_tail_caches.append(nc)
         aux_acc = {k: aux_acc[k] + aux.get(k, 0) for k in aux_acc}
 

@@ -64,6 +64,24 @@ def test_decode_attention_sweep(dtype, atol, L, K, G, hd, window, kvb):
                                rtol=atol)
 
 
+def _stack_layers(leaf, layer, n_layers, seed):
+    """A layer-stacked pool leaf holding `leaf` at `layer` and different
+    values of the same dtype in every other layer."""
+    rng = np.random.default_rng(seed)
+    layers = []
+    for i in range(n_layers):
+        if i == layer:
+            layers.append(np.asarray(leaf))
+        elif leaf.dtype == jnp.int32:
+            layers.append(rng.integers(-1, 64, leaf.shape).astype(np.int32))
+        else:
+            other = rng.normal(size=leaf.shape) * 64
+            if leaf.dtype == jnp.int8:
+                other = np.clip(other, -127, 127)
+            layers.append(np.asarray(jnp.asarray(other).astype(leaf.dtype)))
+    return jnp.asarray(np.stack(layers))
+
+
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
                                         (jnp.bfloat16, 3e-2)])
 @pytest.mark.parametrize("bs,K,G,hd,window", [
@@ -71,15 +89,23 @@ def test_decode_attention_sweep(dtype, atol, L, K, G, hd, window, kvb):
     (8, 1, 4, 16, 40),
     (32, 4, 1, 8, None),
 ])
-def test_paged_decode_attention_sweep(dtype, atol, bs, K, G, hd, window):
+@pytest.mark.parametrize("quant,stacked", [("none", False), ("none", True),
+                                           ("int8", True)])
+def test_paged_decode_attention_sweep(dtype, atol, bs, K, G, hd, window,
+                                      quant, stacked):
     """The block-table kernel vs a dense ring reference: scatter each
-    lane's blocks into a contiguous cache, run the plain decode oracle."""
+    lane's blocks into a contiguous cache, run the plain decode oracle.
+    Stacked cases read layer 1 of a 3-layer pool in place, and must equal
+    the call on that layer's own pool (a one-layer stack) bit for bit."""
+    from repro.models.attention import dequantize_kv, quantize_kv
     b, m_blocks, n_blocks = 3, 4, 9
     rng = np.random.default_rng(0)
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, K, G, hd), dtype)
     kp = jax.random.normal(ks[1], (n_blocks, bs, K, hd), dtype)
     vp = jax.random.normal(ks[2], (n_blocks, bs, K, hd), dtype)
+    kq, k_sc = quantize_kv(kp, quant)
+    vq, v_sc = quantize_kv(vp, quant)
     # each lane owns a random disjoint set of blocks (0 is scratch);
     # lane i's positions run up to its decode cursor, later slots stay -1
     perm = rng.permutation(np.arange(1, n_blocks)).tolist()
@@ -93,13 +119,29 @@ def test_paged_decode_attention_sweep(dtype, atol, bs, K, G, hd, window):
             for o in range(bs):
                 if j * bs + o <= positions[i]:
                     pool_pos[phys, o] = j * bs + o
-    out = ops.paged_decode_attention(
-        q, kp, vp, jnp.asarray(pool_pos), jnp.asarray(tables),
-        jnp.asarray(positions), window=window, backend="interpret")
+    leaves = (kq, vq, jnp.asarray(pool_pos), k_sc, v_sc)
+
+    def call(leaves, layer):
+        kp, vp, pp, ksc, vsc = leaves
+        return ops.paged_decode_attention(
+            q, kp, vp, pp, jnp.asarray(tables), jnp.asarray(positions),
+            layer=layer, window=window, backend="interpret",
+            k_scales=ksc, v_scales=vsc)
+
+    out = call([None if x is None else x[None] for x in leaves], 0)
+    if stacked:
+        layer, n_layers = 1, 3
+        st = [None if x is None else _stack_layers(x, layer, n_layers, i)
+              for i, x in enumerate(leaves)]
+        np.testing.assert_array_equal(
+            np.asarray(call(st, jnp.int32(layer)), np.float32),
+            np.asarray(out, np.float32))
     # dense reference: gather the blocks into [b, m_blocks*bs, ...]
+    kd = dequantize_kv(kq, k_sc, quant, dtype)
+    vd = dequantize_kv(vq, v_sc, quant, dtype)
     safe = np.where(tables >= 0, tables, 0)
-    kd = jnp.asarray(np.asarray(kp)[safe].reshape(b, m_blocks * bs, K, hd))
-    vd = jnp.asarray(np.asarray(vp)[safe].reshape(b, m_blocks * bs, K, hd))
+    kd = jnp.asarray(np.asarray(kd)[safe].reshape(b, m_blocks * bs, K, hd))
+    vd = jnp.asarray(np.asarray(vd)[safe].reshape(b, m_blocks * bs, K, hd))
     cpos = np.where(tables[..., None] >= 0, pool_pos[safe], -1)
     cpos = jnp.asarray(cpos.reshape(b, m_blocks * bs))
     exp = ref.decode_attention_ref(q, kd, vd, cpos, jnp.asarray(positions),
@@ -143,11 +185,13 @@ def test_paged_decode_attention_trimmed_tables(dtype, atol, bs, K, G, hd,
     trim = int((tables >= 0).sum(axis=1).max())
     assert trim < m_blocks
     full = ops.paged_decode_attention(
-        q, kp, vp, jnp.asarray(pool_pos), jnp.asarray(tables),
-        jnp.asarray(positions), window=window, backend="interpret")
+        q, kp[None], vp[None], jnp.asarray(pool_pos)[None],
+        jnp.asarray(tables), jnp.asarray(positions), layer=0, window=window,
+        backend="interpret")
     trimmed = ops.paged_decode_attention(
-        q, kp, vp, jnp.asarray(pool_pos), jnp.asarray(tables[:, :trim]),
-        jnp.asarray(positions), window=window, backend="interpret")
+        q, kp[None], vp[None], jnp.asarray(pool_pos)[None],
+        jnp.asarray(tables[:, :trim]), jnp.asarray(positions), layer=0,
+        window=window, backend="interpret")
     np.testing.assert_allclose(np.asarray(trimmed, np.float32),
                                np.asarray(full, np.float32), atol=atol,
                                rtol=atol)
@@ -311,17 +355,33 @@ def _prefill_case(seed, b, bs, m_blocks, n_blocks, C, K, G, hd, dtype,
         vh = jax.random.normal(ks[4], (b, H, K, hd), dtype)
         hpos = jnp.asarray([[p if p < st else -1 for p in range(H)]
                             for st in starts], jnp.int32)
-        cache = _paged_write_chunk(cache, tables, kh, vh, hpos)
+        cache = _one_layer(_paged_write_chunk(_stacked(cache), 0, tables,
+                                              kh, vh, hpos))
     pos = jnp.asarray([[st + c if c < nv else -1 for c in range(C)]
                        for st, nv in zip(starts, n_valid)], jnp.int32)
     return q, kn, vn, cache, tables, pos
 
 
-def _run_chunk_append(q, kn, vn, cache, tables, pos, backend, window=None):
+def _stacked(cache):
+    return jax.tree.map(lambda a: a[None], cache)
+
+
+def _one_layer(cache, layer=0):
+    return jax.tree.map(lambda a: a[layer], cache)
+
+
+def _run_chunk_append(q, kn, vn, cache, tables, pos, backend, window=None,
+                      layer=None):
+    """_chunk_append on a layer-stacked pool at `layer`; with no layer,
+    on one layer's pool (stacked to one layer and back)."""
     from repro.configs.base import BlockSpec
     from repro.models import attention as A
-    return A._chunk_append(q, kn, vn, cache, BlockSpec(window=window), pos,
-                           tables, A.AttnSettings(backend=backend))
+    one = layer is None
+    o, new = A._chunk_append(q, kn, vn, _stacked(cache) if one else cache,
+                             BlockSpec(window=window), pos, tables,
+                             A.AttnSettings(backend=backend),
+                             layer=0 if one else layer)
+    return o, _one_layer(new) if one else new
 
 
 def _assert_pools_match(got, want, quant):
@@ -349,12 +409,16 @@ def _assert_pools_match(got, want, quant):
     (jnp.float32, "int4", 8e-2),
 ])
 @pytest.mark.parametrize("window", [None, 6])
-def test_paged_prefill_attention_sweep(dtype, quant, atol, window):
+@pytest.mark.parametrize("stacked", [False, True])
+def test_paged_prefill_attention_sweep(dtype, quant, atol, window, stacked):
     """The fused write+attend kernel vs the dense jnp oracle (scatter
     through the table, gather the virtual ring, dense SDPA): odd chunk
     lengths with padding rows, a fresh lane, multi-block chunks landing on
     half-full history blocks, and int8/int4 quantize-on-write — outputs
-    agree and the pools agree EXACTLY (same codes, scales, positions)."""
+    agree and the pools agree EXACTLY (same codes, scales, positions).
+    Stacked cases write and read layer 2 of a 3-layer pool in place: the
+    same outputs and that layer's pool bit for bit as the call on the
+    layer's own pool, and every other layer left untouched."""
     b, bs, m_blocks, n_blocks, C, K, G, hd = 3, 4, 6, 14, 7, 2, 2, 16
     starts, n_valid = (9, 0, 4), (5, 7, 3)   # odd + full + short chunks
     q, kn, vn, cache, tables, pos = _prefill_case(
@@ -365,6 +429,23 @@ def test_paged_prefill_attention_sweep(dtype, quant, atol, window):
     o_ker, cache_ker = _run_chunk_append(q, kn, vn, cache, tables, pos,
                                          "pallas", window=window)
     _assert_pools_match(cache_ker, cache_ref, quant)
+    if stacked:
+        layer, n_layers = 2, 3
+        pool = {k: _stack_layers(v, layer, n_layers, i)
+                for i, (k, v) in enumerate(sorted(cache.items()))}
+        o_st, pool_st = _run_chunk_append(q, kn, vn, pool, tables, pos,
+                                          "pallas", window=window,
+                                          layer=jnp.int32(layer))
+        np.testing.assert_array_equal(np.asarray(o_st, np.float32),
+                                      np.asarray(o_ker, np.float32))
+        for key in pool:
+            got, was = np.asarray(pool_st[key]), np.asarray(pool[key])
+            np.testing.assert_array_equal(got[layer],
+                                          np.asarray(cache_ker[key]),
+                                          err_msg=key)
+            others = [i for i in range(n_layers) if i != layer]
+            np.testing.assert_array_equal(got[others], was[others],
+                                          err_msg=key)
     valid = np.asarray(pos) >= 0
     np.testing.assert_allclose(
         np.asarray(o_ker, np.float32)[valid],

@@ -726,15 +726,16 @@ def test_fused_prefill_avoids_dense_score_transient():
     pos = jax.ShapeDtypeStruct((b, C), jnp.int32)
 
     def tiled(q, kn, vn, kp, vp, pp, tb, pos):
-        return kops.paged_prefill_attention(q, kn, vn, kp, vp, pp, tb, pos,
+        return kops.paged_prefill_attention(q, kn, vn, kp[None], vp[None],
+                                            pp[None], tb, pos, layer=0,
                                             backend="interpret")
 
     def dense(q, kn, vn, kp, vp, pp, tb, pos):
         from repro.configs.base import BlockSpec
         from repro.models import attention as A
-        cache = {"kb": kp, "vb": vp, "pos": pp}
+        cache = {"kb": kp[None], "vb": vp[None], "pos": pp[None]}
         return A._chunk_append(q, kn, vn, cache, BlockSpec(), pos, tb,
-                               A.AttnSettings(backend="naive"))
+                               A.AttnSettings(backend="naive"), layer=0)
 
     score_elems = C * K * G * ctx            # the [C, heads, ctx] matrix
 
@@ -745,3 +746,72 @@ def test_fused_prefill_avoids_dense_score_transient():
 
     assert max_intermediate(dense) >= score_elems
     assert max_intermediate(tiled) < score_elems
+
+
+@pytest.mark.parametrize("backend", ["blocked", "pallas"])
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_scanned_and_unrolled_paged_steps_agree(kv_quant, backend):
+    """The layer scan carries the stacked pool and each layer updates it in
+    place at its own index; the unrolled loop passes the same stack from
+    layer to layer. On a 3-layer model both routes give identical logits,
+    identical whole pools after a chunk tick and several decode ticks, and
+    identical per-block attention mass, through the jnp paths and through
+    the Pallas kernels (interpreted off-TPU)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.configs.base import depth_variant
+    from repro.models import attention as A
+    from repro.models import init_params
+    from repro.models import model as M
+    from repro.runtime import serve_step as SS
+    # f32 weights: with bf16 ones XLA's excess-precision rewrites round
+    # the scan body and the unrolled layers differently (the routes then
+    # differ by ~2e-3 in the logits whatever the cache does)
+    cfg = dataclasses.replace(depth_variant(CFG.reduced(), 3),
+                              param_dtype="float32")
+    assert cfg.repeats == 3
+    lanes, n_blocks, block, context, C = 3, 12, 4, 16, 6
+    params = init_params(jax.random.PRNGKey(1), cfg)
+    pool0 = SS.init_paged_pool(cfg, lanes, n_blocks, block, context,
+                               kv_quant=kv_quant)
+    # lanes 0 and 2 hold blocks; lane 1 stays empty (an inert row)
+    tables = jnp.asarray([[1, 4, 7, -1], [-1] * 4, [2, 5, 9, 3]], jnp.int32)
+    lane_ids = jnp.arange(lanes, dtype=jnp.int32)
+    rng = np.random.default_rng(0)
+    chunk_tok = jnp.asarray(rng.integers(1, cfg.vocab_size, (lanes, C)),
+                            jnp.int32)
+    chunk_pos = jnp.asarray([list(range(C)), [-1] * C,
+                             list(range(C - 2)) + [-1, -1]], jnp.int32)
+    cursor = np.array([C, -1, C - 2], np.int32)
+
+    def run(scan_layers):
+        settings = M.ModelSettings(
+            attn=A.AttnSettings(backend=backend, track_mass=True),
+            scan_layers=scan_layers)
+        chunk = jax.jit(SS.make_chunk_prefill_step(cfg, settings),
+                        static_argnames=("context",))
+        decode = jax.jit(SS.make_compact_decode_step(cfg, settings),
+                         static_argnames=("context",))
+        logits, pool = chunk(params, chunk_tok, chunk_pos, tables, lane_ids,
+                             pool0, context=context)
+        outs = [logits]
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        for t in range(4):
+            pos = jnp.asarray(np.where(cursor >= 0, cursor + t, -1),
+                              jnp.int32)
+            logits, pool, mass = decode(params, tok[:, None], pos, tables,
+                                        lane_ids, pool, context=context)
+            outs += [logits, mass]
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return outs, pool
+
+    outs_scan, pool_scan = run(True)
+    outs_loop, pool_loop = run(False)
+    for a, b in zip(outs_scan, outs_loop):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(jax.tree.leaves(pool_scan), jax.tree.leaves(pool_loop)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the ticks wrote every layer of the pool: live positions in layer 2
+    written = np.asarray(pool_scan["units"][0]["pos"])[2]
+    assert (written >= 0).sum() == (C + 4) + (C - 2 + 4)
