@@ -44,7 +44,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     cell, config, tspec, _, _ = RUN.load_cell(args.workload, args.rehearse)
-    jax = RUN.setup_jax()
+    jax = RUN.setup_jax(cell["chips"] if args.rehearse else 1)
     from harness import check, serve
     from harness.traffic import Traffic
 
@@ -53,7 +53,7 @@ def main(argv=None) -> int:
         RUN.log(f"calibrate.py: needs a TPU, JAX found "
                 f"{devices[0].platform!r}")
         return 1
-    used = devices[:cell["chips"]] if not args.rehearse else devices[:1]
+    used = devices[:cell["chips"]]
     ref = importlib.import_module(f"references.{config['family']}")
     adapter = importlib.import_module(f"adapters.{config['family']}")
     clock = serve.CompileClock()

@@ -108,19 +108,35 @@ def call_flops(ref, config, call) -> float:
 PALLAS = 'custom_call_target="tpu_custom_call"'
 
 
+def per_chip_dims(ref, config: Dict) -> Dict[str, int]:
+    """The reference's sizes with a layer's query and KV heads divided over
+    the chips that split the layer (`serving.model_parallel`, which the
+    harness's build holds the mesh to): what one chip's attention kernel
+    computes, since the program runs each kernel once per chip on its own
+    KV-head shard."""
+    m = dict(ref.dims(config))
+    mp = config["serving"]["model_parallel"]
+    if m["H"] % mp or m["K"] % mp:
+        raise ValueError(f"{m['H']} query and {m['K']} KV heads do not "
+                         f"divide over {mp} chips")
+    m["H"] //= mp
+    m["K"] //= mp
+    return m
+
+
 def kernel_share(ctx, kind: str, module: str, cost_fn) -> "float | None":
     """Roofline share (%) of the Pallas kernel inside the `module` program
     (the step the executor calls of `kind` run), over the calls made while
-    tracing: the sum of each call's least time (per layer, from `cost_fn`)
-    over the sum of the kernel's device time. None when the trace holds no
-    such call, or when the kernel events do not number one per layer per
-    call."""
+    tracing: the sum of each call's least time (per layer, from `cost_fn`,
+    at one chip's share of the heads) over the sum of the kernel's device
+    time on the first chip used. None when the trace holds no such call, or
+    when the kernel events do not number one per layer per call."""
     from harness import trace as T
     if ctx.trace is None or ctx.peaks is None:
         return None
     calls = [c for c in ctx.calls if c.kind == kind]
-    m = ctx.ref.dims(ctx.config)
-    events = T.ops_in(ctx.trace, 0, module, PALLAS)
+    m = per_chip_dims(ctx.ref, ctx.config)
+    events = T.ops_in(ctx.trace, ctx.devices[0], module, PALLAS)
     if not calls or len(events) != m["L"] * len(calls):
         return None
     least = 0.0

@@ -20,6 +20,7 @@ class Context:
     """What a reader may use."""
     config: Dict                 # the configuration file
     ref: object                  # the configuration's reference module
+    devices: List[int]           # ids of the chips the run's mesh is on
     peaks: Optional[Dict]        # the chip's peaks (None off a chip)
     trace: Optional[object]      # harness.trace.Trace of the traced window
     calls: List                  # executor calls made while tracing

@@ -161,6 +161,11 @@ def build(config: Dict, traffic, seed: int, devices, adapter) -> System:
     n_blocks = splan.pool_blocks(n_lanes, context)
     t1 = time.perf_counter()
     mesh, strategy = splan.execution.build(devices)
+    if mesh.shape.get("model", 1) != sv["model_parallel"]:
+        raise RuntimeError(
+            f"the mesh {dict(mesh.shape)} splits a layer over "
+            f"{mesh.shape.get('model', 1)} devices; the configuration "
+            f"states model_parallel {sv['model_parallel']}")
     stack = contextlib.ExitStack()
     stack.enter_context(mesh)
     stack.enter_context(axis_rules(strategy.rules(), mesh=mesh))

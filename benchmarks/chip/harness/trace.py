@@ -101,8 +101,10 @@ def busy_s(tr: Trace, device: int) -> float:
                ) * 1e-9
 
 
-def mean_busy_s(tr: Trace) -> float:
-    return sum(busy_s(tr, d) for d in tr.devices) / len(tr.devices)
+def mean_busy_s(tr: Trace, devices: List[int]) -> float:
+    """Busy seconds averaged over the devices a run used (their ids); a
+    device of the trace that the run did not use counts nothing."""
+    return sum(busy_s(tr, d) for d in devices) / len(devices)
 
 
 def short_op(text: str) -> Tuple[str, str]:

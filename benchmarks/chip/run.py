@@ -21,7 +21,8 @@ failed, metrics, device[, breakdown], compared. A run that finds no TPU, or
 fewer chips than the cell asks for, exits 1 and prints no result.
 
 --rehearse runs the same control flow on the CPU at the configuration's
-`rehearsal` sizes and prints no device metric.
+`rehearsal` sizes, on as many virtual CPU devices as the cell has chips,
+and prints no device metric.
 
 The persistent compilation cache is `<checkout>/.bench_jax_cache`, so only
 a cell's first run in a checkout compiles.
@@ -78,11 +79,16 @@ def load_cell(name: str, rehearse: bool):
     return cell, config, traffic, e2e, layer
 
 
-def setup_jax():
+def setup_jax(cpu_devices: int = 1):
     """Import JAX with the persistent compilation cache at the checkout's
     fixed directory (off on the CPU), and the program and the harness on
-    the path."""
+    the path. `cpu_devices` > 1 gives the CPU backend that many devices (a
+    rehearsal of a cell on several chips)."""
     os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
+    if cpu_devices > 1:
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+            os.environ.get("XLA_FLAGS", ""),
+            f"--xla_force_host_platform_device_count={cpu_devices}"]))
     sys.path[:0] = [os.path.join(ROOT, "src"), HERE]
     import jax
     if jax.default_backend() != "cpu":
@@ -133,24 +139,24 @@ def main(argv=None) -> int:
 
     cell, config, tspec, e2e, layer = load_cell(args.workload,
                                                 args.rehearse)
-    jax = setup_jax()
+    chips = cell["chips"]
+    jax = setup_jax(chips if args.rehearse else 1)
     from harness import check, costs, metrics, serve
     from harness import trace as T
     from harness.traffic import Traffic
 
     devices = jax.devices()
-    chips = cell["chips"]
     peaks = None
     if not args.rehearse:
         if devices[0].platform != "tpu":
             log(f"run.py: needs a TPU, JAX found {devices[0].platform!r}")
             return 1
-        if len(devices) < chips:
-            log(f"run.py: the cell needs {chips} chips, JAX sees "
-                f"{len(devices)}")
-            return 1
         peaks = costs.peaks(devices[0].device_kind)
-    used = devices[:chips] if not args.rehearse else devices[:1]
+    if len(devices) < chips:
+        log(f"run.py: the cell needs {chips} chips, JAX sees "
+            f"{len(devices)}")
+        return 1
+    used = devices[:chips]
     ref = importlib.import_module(f"references.{config['family']}")
     adapter = importlib.import_module(f"adapters.{config['family']}")
 
@@ -227,7 +233,8 @@ def main(argv=None) -> int:
         result["metrics"] = {}
     elif args.trace:
         ctx = metrics.Context(
-            config=config, ref=ref, peaks=peaks, trace=tr,
+            config=config, ref=ref, devices=[dv.id for dv in used],
+            peaks=peaks, trace=tr,
             calls=traced_calls, ticks=ticks, counters=d.counters,
             memory_peak_bytes=peak, promised_bytes=promised,
             kv_block=kv_block)
@@ -235,9 +242,10 @@ def main(argv=None) -> int:
         result["metrics"] = {m["name"]: {"value": vals[m["name"]],
                                          "unit": m["unit"]}
                              for m in layer if m["name"] in vals}
-        device.update(busy_s=T.mean_busy_s(tr), window_s=tr.window_s)
-        result["breakdown"] = {"device_ops": T.top_ops(tr, 0),
-                               "idle_gaps": T.idle_gaps(tr, 0)}
+        device.update(busy_s=T.mean_busy_s(tr, ctx.devices),
+                      window_s=tr.window_s)
+        result["breakdown"] = {"device_ops": T.top_ops(tr, ctx.devices[0]),
+                               "idle_gaps": T.idle_gaps(tr, ctx.devices[0])}
     else:
         vals = end_to_end(d, args.seconds,
                           [m["name"] for m in e2e if m["name"] != "setup_s"])

@@ -39,13 +39,13 @@ def main(argv=None) -> int:
     cell, config, tspec, _, _ = RUN.load_cell(args.workload, args.rehearse)
     if tspec["loop"] != "open":
         ap.error("a sweep needs an open-loop traffic mix")
-    jax = RUN.setup_jax()
+    jax = RUN.setup_jax(cell["chips"] if args.rehearse else 1)
     from harness import serve
     from harness.stats import percentile
     from harness.traffic import Traffic
 
     devices = jax.devices()
-    used = devices[:cell["chips"]] if not args.rehearse else devices[:1]
+    used = devices[:cell["chips"]]
     adapter = importlib.import_module(f"adapters.{config['family']}")
     clock = serve.CompileClock()
     sysm = None

@@ -13,10 +13,10 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(os.path.dirname(BENCH))
 
 
-def test_control_is_not_correct_and_the_program_is():
+def calibrate(workload):
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     p = subprocess.run([sys.executable, os.path.join(BENCH, "calibrate.py"),
-                        "--workload", "nemo12b-chat", "--seeds", "3",
+                        "--workload", workload, "--seeds", "3",
                         "--control", "3", "--seconds", "2", "--rehearse"],
                        capture_output=True, text=True, env=env, cwd=ROOT,
                        timeout=900)
@@ -26,3 +26,12 @@ def test_control_is_not_correct_and_the_program_is():
     assert summary["correct"] == [True] * 3, summary
     assert summary["control_correct"] == [False] * 3, summary
     assert summary["upper"] >= 3 * summary["lower"], summary
+
+
+def test_control_is_not_correct_and_the_program_is():
+    calibrate("nemo12b-chat")
+
+
+def test_sharded_control_is_not_correct_and_the_program_is():
+    """The four-chip cell's control, on four virtual CPU devices."""
+    calibrate("nemo12b-tp4-batch")

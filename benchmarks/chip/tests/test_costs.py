@@ -18,6 +18,12 @@ def full():
         return json.load(f)
 
 
+@pytest.fixture(scope="module")
+def tp4():
+    with open(os.path.join(CONFIGS, "nemo12b-tp4.json")) as f:
+        return json.load(f)
+
+
 def test_matmul_flops_are_twice_the_weights(full):
     from repro.configs.base import param_count
     m = R.dims(full)
@@ -69,6 +75,37 @@ def test_call_flops_match_per_token_sums(full):
             + R.token_flops(full, 0, True) - R.token_flops(full, 0, False)
             + sum(R.token_flops(full, i + 1, False) for i in range(256)))
     assert costs.call_flops(R, full, chunk) == pytest.approx(want)
+
+
+def test_one_chip_of_model_parallel_holds_a_quarter_of_the_heads(full,
+                                                                   tp4):
+    assert costs.per_chip_dims(R, full) == R.dims(full)
+    m = costs.per_chip_dims(R, tp4)
+    assert (m["H"], m["K"], m["hd"]) == (8, 2, 128)
+    odd = {**tp4, "serving": {**tp4["serving"], "model_parallel": 3}}
+    with pytest.raises(ValueError):
+        costs.per_chip_dims(R, odd)
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "chunk_prefill"])
+def test_per_chip_counts_are_a_quarter_of_the_layer(tp4, kernel):
+    """At one chip's heads a kernel call needs a quarter of the layer's
+    operations and a quarter of its bytes, but for the block positions
+    (4 bytes each), which every chip reads and writes whole."""
+    m = R.dims(tp4)
+    c = costs.per_chip_dims(R, tp4)
+    bs = tp4["serving"]["kv_block"]
+    if kernel == "paged_decode":
+        lanes = [(0, 1), (127, 1), (128, 2), (4000, 32)]
+        pos = sum(n * bs for _, n in lanes) * 4
+    else:
+        lanes = [(0, 256), (256, 256), (512, 100)]
+        pos = sum(-(-s // bs) * bs + n for s, n in lanes) * 4
+    fn = getattr(costs, kernel)
+    flops, nbytes = fn(lanes, H=m["H"], K=m["K"], hd=m["hd"], block=bs)
+    cf, cb = fn(lanes, H=c["H"], K=c["K"], hd=c["hd"], block=bs)
+    assert cf * 4 == flops
+    assert (cb - pos) * 4 == nbytes - pos
 
 
 def test_roofline_takes_the_larger_bound():

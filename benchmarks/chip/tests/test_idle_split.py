@@ -77,7 +77,8 @@ def recorded():
 
 
 def test_recorded_idle_share_agrees_with_its_reader(recorded):
-    ctx = metrics.Context(config={}, ref=None, peaks=None, trace=recorded,
+    ctx = metrics.Context(config={}, ref=None, devices=[0], peaks=None,
+                          trace=recorded,
                           calls=[], ticks=[], counters={},
                           memory_peak_bytes=None, promised_bytes=1.0,
                           kv_block=1)

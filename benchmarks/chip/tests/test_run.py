@@ -40,6 +40,29 @@ FAULTS = {
             return orig(self, tokens, positions, tables, lanes=lanes)
         E.PagedJaxExecutor.decode = fault
     """,
+    # the exchange between chips left out: each output projection (the
+    # attention's and the MLP's, whose inputs are split over "model"; at
+    # the rehearsal sizes both map twice the model width back to it) keeps
+    # its own chip's partial sum instead of all-reducing them
+    "exchange_left_out": """
+        import jax
+        from jax.sharding import PartitionSpec as P
+        from repro.models import layers as Ly
+        from repro.parallel import axes as pax
+        orig = Ly.matmul
+        def fault(x, w):
+            mesh = pax.current_mesh()
+            if (mesh is None or mesh.shape.get("model", 1) == 1
+                    or w.ndim != 2 or w.shape[0] == w.shape[1]
+                    or w.shape[1] != x.shape[-1] // 2):
+                return orig(x, w)
+            lead = (None,) * (x.ndim - 1)
+            return jax.shard_map(orig, mesh=mesh,
+                                 in_specs=(P(*lead, "model"),
+                                           P("model", None)),
+                                 out_specs=P(), check_vma=False)(x, w)
+        Ly.matmul = fault
+    """,
 }
 
 
@@ -63,7 +86,8 @@ def run(workload, seed, fault=""):
     return lines, json.loads(lines[-1])
 
 
-@pytest.mark.parametrize("workload", ["nemo12b-chat", "nemo12b-batch"])
+@pytest.mark.parametrize("workload", ["nemo12b-chat", "nemo12b-batch",
+                                      "nemo12b-tp4-batch"])
 def test_sound_run_is_correct(workload):
     lines, res = run(workload, 2**31 + 99)
     assert "compiles_in_window=0" in lines
@@ -71,6 +95,8 @@ def test_sound_run_is_correct(workload):
     assert list(res) == ["correct", "attempted", "failed", "metrics",
                          "device", "compared"]
     assert res["attempted"] > 0 and res["failed"] == 0
+    # a cell of four chips rehearses on four devices, sharded
+    assert res["device"]["count"] == (4 if "tp4" in workload else 1)
     if workload == "nemo12b-chat":
         # every request due in the window is offered and served, also the
         # last, due while a tick ran past the close (20 req/s x 2 s)
@@ -79,10 +105,44 @@ def test_sound_run_is_correct(workload):
     assert gap["value"] <= gap["limit"]
 
 
-@pytest.mark.parametrize("fault", sorted(FAULTS))
+@pytest.mark.parametrize("fault", ["decode_cache_lost",
+                                   "decode_token_altered",
+                                   "prefill_token_altered"])
 def test_broken_path_is_not_correct(fault):
     _, res = run("nemo12b-chat", 4242, FAULTS[fault])
     assert res["correct"] is False, res["compared"]
+
+
+@pytest.mark.parametrize("fault", ["exchange_left_out",
+                                   "decode_token_altered"])
+def test_broken_sharded_path_is_not_correct(fault):
+    _, res = run("nemo12b-tp4-batch", 4343, FAULTS[fault])
+    assert res["correct"] is False, res["compared"]
+
+
+def test_build_refuses_a_mesh_other_than_the_configuration():
+    """A cell of four chips built on one device does not shrink its model
+    axis to fit: the build raises."""
+    code = textwrap.dedent("""
+        import importlib, sys
+        sys.path[:0] = [{bench!r}]
+        import run
+        cell, config, tspec, _, _ = run.load_cell("nemo12b-tp4-batch", True)
+        jax = run.setup_jax()
+        from harness import serve
+        from harness.traffic import Traffic
+        adapter = importlib.import_module("adapters.mistral")
+        try:
+            serve.build(config, Traffic(tspec, 1, config["vocab_size"]), 1,
+                        jax.devices()[:1], adapter)
+        except RuntimeError as e:
+            print("refused:", e)
+    """).format(bench=BENCH)
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, cwd=ROOT, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    assert "refused:" in p.stdout and "model_parallel 4" in p.stdout
 
 
 def test_no_chip_no_result():
